@@ -45,14 +45,15 @@ def _engine_rows(name: str, K: int) -> list[str]:
         wf = build_wavefront_plan(sched, plan, H)
         waves = wave_inputs(wf, step_keys)
         packed = pack_state(state)
-        runner = rfast_wavefront_scan(plan, gfn, 5e-3, donate=False)
+        runner = rfast_wavefront_scan(plan, gfn, 5e-3, donate=False,
+                                      p_real=prob.p)
         us_wave = measure_us(runner, packed, waves, reps=3) / Ks
 
         # same schedule through the fused-grid commit (dispatch-resolved:
         # compiled on TPU, the jnp emulation twin on CPU) — the maxerr
         # keeps the grid path honest on real engine traffic
         runner_p = rfast_wavefront_scan(plan, gfn, 5e-3, donate=False,
-                                        impl="pallas")
+                                        impl="pallas", p_real=prob.p)
         us_wave_p = measure_us(runner_p, packed, waves, reps=3) / Ks
         werr = max(float(jnp.abs(a - b).max()) for a, b in
                    zip(runner(packed, waves), runner_p(packed, waves)))

@@ -118,9 +118,8 @@ def time_to_sustained_loss(metrics: list[dict], target: float) -> float:
 
 def eval_fn_for(prob):
     """Uniform eval hook: every algorithm hands over its *iterate* —
-    an (n, p) per-node stack, a (p,) single model, or the R-FAST state."""
-    def eval_fn(state_or_x, t):
-        x = state_or_x.x if hasattr(state_or_x, "x") else state_or_x
+    an (n, p) per-node stack or a (p,) single model."""
+    def eval_fn(x, t):
         xb = jnp.asarray(x)
         if xb.ndim == 2:
             xb = xb.mean(0)

@@ -36,6 +36,8 @@ def _row_to_record(suite: str, row: str) -> dict:
 
 
 def main() -> None:
+    from repro.launch.xla_env import configure_compile_cache
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated subset: topologies,scaling,"

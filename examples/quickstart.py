@@ -27,8 +27,8 @@ print(f"realized delay bound D={sched.D}, activation bound T={sched.T}")
 
 
 # 4. run the exact Algorithm-2 recursion
-def eval_fn(state, t):
-    x_bar = jnp.asarray(state.x).mean(0)
+def eval_fn(x, t):
+    x_bar = x.mean(0)
     return {"loss": float(prob.mean_loss(x_bar)),
             "acc": float(prob.accuracy(x_bar)), "t": t}
 

@@ -20,7 +20,7 @@ gfn = prob.grad_fn()
 
 
 def eval_fn(x, t):
-    xb = jnp.asarray(x.x if hasattr(x, "x") else x)
+    xb = jnp.asarray(x)
     if xb.ndim == 2:
         xb = xb.mean(0)
     return {"loss": float(prob.mean_loss(xb)), "t": t}

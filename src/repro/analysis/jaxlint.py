@@ -47,9 +47,9 @@ def _sub_jaxprs(params: dict):
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, jax.extend.core.ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, jax.extend.core.Jaxpr):
                 yield x
 
 
@@ -69,7 +69,7 @@ def audit_jaxpr(closed, *, subject,
     """RF201 (host callbacks in loop bodies), RF202 (f64/c128
     intermediates), RF203 (materialized rank>=3 broadcast/gather blowups
     above the element threshold) over one traced jaxpr."""
-    jaxpr = closed.jaxpr if isinstance(closed, jax.core.ClosedJaxpr) \
+    jaxpr = closed.jaxpr if isinstance(closed, jax.extend.core.ClosedJaxpr) \
         else closed
     diags = []
     wide_seen = collections.Counter()
@@ -239,7 +239,7 @@ def audit_mesh_collectives(closed, *, subject, state_bytes_threshold
     i.e. <= threshold/4 — so a collective at or above the threshold is
     never the designed data flow.
     """
-    jaxpr = closed.jaxpr if isinstance(closed, jax.core.ClosedJaxpr) \
+    jaxpr = closed.jaxpr if isinstance(closed, jax.extend.core.ClosedJaxpr) \
         else closed
     diags = []
     for eqn, _ in iter_eqns(jaxpr):
@@ -289,6 +289,7 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
                                   rfast_wavefront_scan, wave_inputs)
     from ..core.topology import get_topology
     from ..kernels.rfast_update.grid import commit_grid
+    from ..kernels.rfast_update.kernel import LANE
 
     rng = np.random.default_rng(seed)
     C = jnp.asarray(rng.normal(0, 1, (n, p)), jnp.float32)
@@ -326,13 +327,14 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
     waves = wave_inputs(wf, keys)
     for impl in ("jnp", "pallas"):
         runner = rfast_wavefront_scan(plan, gfn, gamma, donate=False,
+                                      p_real=p,
                                       impl=impl)
         cj = jax.make_jaxpr(runner)(packed, waves)
         diags += audit_jaxpr(cj, subject=f"rfast_wavefront_scan[{impl}]",
                              **kw)
         audited.append(f"rfast_wavefront_scan[{impl}]")
     diags += audit_donation(
-        rfast_wavefront_scan(plan, gfn, gamma, donate=True),
+        rfast_wavefront_scan(plan, gfn, gamma, donate=True, p_real=p),
         (packed, waves), (0,), subject="rfast_wavefront_scan[donate]")
     audited.append("rfast_wavefront_scan[donate]")
 
@@ -352,22 +354,23 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
            for s, c in zip((sched, sched_b), pads)]
     fleet = flatten_plans(stack_plans(wfs))
     S = 2
+    row = (-(-p // LANE), LANE)
     fpacked = PackedState(
-        nodes=jnp.zeros((S * n, 4, p), jnp.float32),
-        rho2=jnp.zeros((2 * S * e_a, p), jnp.float32),
-        v_hist=jnp.zeros((H_f, S * n, p), jnp.float32),
-        rho_hist=jnp.zeros((H_f, S * e_a, p), jnp.float32))
+        nodes=jnp.zeros((S * n, 4) + row, jnp.float32),
+        rho2=jnp.zeros((2 * S * e_a,) + row, jnp.float32),
+        v_hist=jnp.zeros((H_f, S * n) + row, jnp.float32),
+        rho_hist=jnp.zeros((H_f, S * e_a) + row, jnp.float32))
     fwaves = wave_inputs(fleet, jnp.zeros((S * K, 2), jnp.uint32))
     for impl in ("jnp", "pallas"):
         sweep = rfast_sweep_scan(gfn, gamma, ko=ko_max, n_per_lane=n,
-                                 donate=False, impl=impl)
+                                 donate=False, p_real=p, impl=impl)
         cj = jax.make_jaxpr(sweep)(fpacked, fwaves)
         diags += audit_jaxpr(cj, subject=f"rfast_sweep_scan[{impl}]",
                              **kw)
         audited.append(f"rfast_sweep_scan[{impl}]")
     diags += audit_donation(
         rfast_sweep_scan(gfn, gamma, ko=ko_max, n_per_lane=n,
-                         donate=True), (fpacked, fwaves), (0,),
+                         donate=True, p_real=p), (fpacked, fwaves), (0,),
         subject="rfast_sweep_scan[donate]")
     audited.append("rfast_sweep_scan[donate]")
 
@@ -379,10 +382,11 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
         np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     mpacked = jax.tree.map(lambda a: a[None], fpacked)
     mwaves = jax.tree.map(lambda a: a[None], fwaves)
-    state_bytes = S * n * 4 * p * np.dtype(np.float32).itemsize
+    state_bytes = S * n * 4 * row[0] * LANE * np.dtype(np.float32).itemsize
     for impl in ("jnp", "pallas"):
         mrunner = _mesh_sweep_scan(gfn, gamma, ko=ko_max, n_per_lane=n,
-                                   mesh=mesh, donate=False, impl=impl)
+                                   mesh=mesh, donate=False, p_real=p,
+                                   impl=impl)
         cj = jax.make_jaxpr(mrunner)(mpacked, mwaves)
         diags += audit_jaxpr(cj, subject=f"mesh_sweep_scan[{impl}]", **kw)
         diags += audit_mesh_collectives(
@@ -391,7 +395,7 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
         audited.append(f"mesh_sweep_scan[{impl}]")
     diags += audit_donation(
         _mesh_sweep_scan(gfn, gamma, ko=ko_max, n_per_lane=n, mesh=mesh,
-                         donate=True), (mpacked, mwaves), (0,),
+                         donate=True, p_real=p), (mpacked, mwaves), (0,),
         subject="mesh_sweep_scan[donate]")
     audited.append("mesh_sweep_scan[donate]")
 
@@ -414,7 +418,7 @@ def audit_engines(*, n=5, p=8, K=48, seed=0,
                           lambda i, x, key: x,
                           jax.random.PRNGKey(seed), H_e)
         runner_e = rfast_wavefront_scan(plan_e, lambda i, x, key: x,
-                                        gamma, donate=False)
+                                        gamma, donate=False, p_real=p)
         cj = jax.make_jaxpr(runner_e)(
             pack_state(st_e),
             wave_inputs(wf_e, jax.random.split(jax.random.PRNGKey(0),

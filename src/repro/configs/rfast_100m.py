@@ -1,12 +1,13 @@
 """rfast-100m — the ~100M-param LM used by the end-to-end R-FAST training
 driver (examples/train_rfast.py).  Llama-style dense decoder.
 
-At full scale the flat parameter vector (~134M fp32, ~0.5 GiB — and the
-wavefront engine carries 4 node slots plus the ρ/history rings of it per
-node) does not fit a single small device: train through the mesh-mapped
-sweep with the flat axis sharded over ``model`` —
-``launch.train --scenario <name> --param-shards M`` or
-``run_sweep(mesh=make_sweep_mesh(lanes=1, param_shards=M), ...)``; the
+Its flat parameter vector is p = 124,668,672 fp32 (0.46 GiB), and the
+wavefront engine keeps four node slots plus the ρ and history rings of it
+per node.  With 2 nodes (binary tree) that is 8.8 GiB, which fits one
+16 GiB v5e chip: ``launch.train --scenario <name> --nodes 2``.  With 4 or
+more nodes it does not: shard the flat axis over ``model`` with
+``launch.train --scenario <name> --nodes 4 --param-shards 4`` (or
+``run_sweep(mesh=make_sweep_mesh(lanes=1, param_shards=M), ...)``); the
 ``lm100m/*`` rows in benchmarks/bench_scaling.py pin this path.
 """
 from repro.models.config import ModelConfig

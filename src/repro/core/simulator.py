@@ -60,10 +60,13 @@ import numpy as np
 
 from ..kernels.rfast_update import dispatch
 from ..kernels.rfast_update.grid import block_pad_width, commit_grid
+from ..kernels.rfast_update.kernel import LANE
+
+SUBLANES = 8        # rows of one fp32 TPU tile: (SUBLANES, LANE)
 from ..kernels.rfast_update.ops import rfast_commit
 from .paramvec import GradProvider, as_grad_fn
 from .plan import CommPlan, as_comm_plan, pad_comm_plan
-from .runtime_sharded import _shard_map, packed_sweep_specs
+from .runtime_sharded import packed_sweep_specs
 from .protocol import consensus_mix, descent_step, mailbox_merge, tracking_step
 from .schedule import (Schedule, build_wavefront_plan, concat_plans,
                        flatten_plans, grid_gather_tables, pad_plan,
@@ -265,16 +268,230 @@ class PackedState(NamedTuple):
     """Device layout of the wavefront engine: node variables fused into
     one array and ρ/ρ̃ stacked, so a wavefront commits with four scatters.
 
-    * ``nodes``  — (n, 4, p): rows x, v, z, g_prev per node.
-    * ``rho2``   — (2·E_A, p): ρ rows then ρ̃ rows.
-    * ``v_hist`` — (H, n, p) delta rows indexed (writer count mod H, node).
-    * ``rho_hist`` — (H, E_A, p) delta rows (sender count mod H, edge).
+    Every array is *lane-dense*: the flat parameter axis is stored as
+    ``(R, LANE)`` rows (``p_pad = R·LANE``, zero tail), the TPU's native
+    tiling, so the grid kernel reads ``(BLK_R, LANE)`` blocks of any row
+    without a relayout copy of the state.
+
+    * ``nodes``  — (n, 4, R, LANE): rows x, v, z, g_prev per node.
+    * ``rho2``   — (2·E_A, R, LANE): ρ rows then ρ̃ rows.
+    * ``v_hist`` — (H, n, R, LANE) delta rows (writer count mod H, node).
+    * ``rho_hist`` — (H, E_A, R, LANE) delta rows (sender count mod H,
+      edge).
     """
 
     nodes: jnp.ndarray
     rho2: jnp.ndarray
     v_hist: jnp.ndarray
     rho_hist: jnp.ndarray
+
+
+def _pad_width(p: int, shards: int = 1, *, blocks: bool = False) -> int:
+    """Padded flat width of the packed state: every shard's slice is
+    whole ``(SUBLANES, LANE)`` tiles, or whole ``(BLK_R, LANE)`` blocks
+    when the compiled grid kernel reads it (``blocks``).  A ragged tile
+    row costs the TPU a copy of every array an update writes in place."""
+    if blocks:
+        return block_pad_width(p, shards)
+    per = SUBLANES * LANE
+    loc = -(-int(p) // int(shards))
+    return int(shards) * (-(-loc // per) * per)
+
+
+def _to_lanes(a: jnp.ndarray, p_pad: int) -> jnp.ndarray:
+    """``(..., p)`` -> lane-dense ``(..., p_pad // LANE, LANE)``."""
+    p = a.shape[-1]
+    if p_pad != p:
+        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, p_pad - p)])
+    return a.reshape(a.shape[:-1] + (p_pad // LANE, LANE))
+
+
+def _from_lanes(a: jnp.ndarray, p: int) -> jnp.ndarray:
+    """Lane-dense ``(..., R, LANE)`` -> flat ``(..., p)``.  Whole pad rows
+    go before the relayout, which then writes the result directly."""
+    a = a[..., :-(-p // LANE), :]
+    flat = a.reshape(a.shape[:-2] + (-1,))
+    return flat if flat.shape[-1] == p else flat[..., :p]
+
+
+@partial(jax.jit, static_argnames=("index", "p"))
+def _take(a: jnp.ndarray, index: tuple, p: int) -> jnp.ndarray:
+    """``a[index]`` back in the flat layout, as one program: only the
+    indexed rows are read.  ``index`` holds ints and ``(start, stop)``
+    pairs (static, so one compile per distinct slice)."""
+    idx = tuple(slice(*i) if isinstance(i, tuple) else i for i in index)
+    return _from_lanes(a[idx], p)
+
+
+def pack_state(state: RFASTState, *, e_a: int | None = None,
+               p_pad: int | None = None) -> PackedState:
+    """Device layout for the wavefront/sweep engines.
+
+    ``e_a`` pads the ρ state to a larger flat layout (fleet sweeps
+    normalize every lane to the fleet-wide max A-edge count; the extra
+    zero rows are never referenced by a real lane and the matching
+    WavefrontPlan must be built/padded against the same ``e_a``).
+
+    ``p_pad`` is the padded flat width (a multiple of ``LANE``; default:
+    the next one above ``p``; the compiled grid kernel needs whole
+    blocks).  The zero tail is inert under the linear protocol — pass
+    the real ``p`` back via the engines' ``p_real`` /
+    :func:`unpack_state`'s ``p``.
+    """
+    rho, rho_buf, rho_hist = state.rho, state.rho_buf, state.rho_hist
+    if e_a is not None and e_a != rho.shape[0]:
+        if e_a < rho.shape[0]:
+            raise ValueError(f"e_a={e_a} < state's A-edge count "
+                             f"{rho.shape[0]}")
+        pad = e_a - rho.shape[0]
+        rho = jnp.pad(rho, ((0, pad), (0, 0)))
+        rho_buf = jnp.pad(rho_buf, ((0, pad), (0, 0)))
+        rho_hist = jnp.pad(rho_hist, ((0, 0), (0, pad), (0, 0)))
+    p = state.x.shape[-1]
+    if p_pad is None:
+        p_pad = _pad_width(p)
+    if p_pad < p or p_pad % LANE:
+        raise ValueError(f"p_pad={p_pad} must be a multiple of {LANE} "
+                         f"and >= the state's p={p}")
+    return PackedState(
+        nodes=_to_lanes(jnp.stack([state.x, state.v, state.z,
+                                   state.g_prev], axis=1), p_pad),
+        rho2=_to_lanes(jnp.concatenate([rho, rho_buf], axis=0), p_pad),
+        v_hist=_to_lanes(state.v_hist, p_pad),
+        rho_hist=_to_lanes(rho_hist, p_pad),
+    )
+
+
+def unpack_state(packed: PackedState, k, *, p: int | None = None
+                 ) -> RFASTState:
+    """The :class:`RFASTState` of a packed state, on the host, stripped to
+    width ``p`` (default: the whole padded width)."""
+    return _lane_states(packed, k, S=1, n=packed.nodes.shape[0],
+                        e_a=packed.rho_hist.shape[1], p=p)[0]
+
+
+def _lane_states(packed: PackedState, k, *, S: int, n: int, e_a: int,
+                 p: int | None = None, e_a_lane=None, group_size=None,
+                 consume: bool = False) -> list[RFASTState]:
+    """Per-lane :class:`RFASTState`s of a fleet's packed state (lane
+    blocks: nodes ``[s·n, (s+1)·n)``, ρ ``[s·e_a, ·)`` with ρ̃ at offset
+    ``S·e_a``; ``e_a_lane`` strips each lane's ρ state back to its real
+    A-edge count; ``group_size`` reads the mesh engine's group-stacked
+    layout, lane ``s`` in group ``s // group_size``).
+
+    Built on the host: each packed array is copied out whole, so the
+    device needs no memory beyond the state itself (a device-side
+    relayout would need as much again).  ``consume=True`` is for the
+    engines' own final state: each array leaves the device once copied.
+    """
+    if p is None:
+        p = packed.nodes.shape[-2] * LANE
+    if e_a_lane is None:
+        e_a_lane = [e_a] * S
+    host = {}
+    for name, arr in zip(PackedState._fields, packed):
+        host[name] = np.asarray(arr)
+        if consume:
+            arr.delete()
+    flat = lambda a: a.reshape(a.shape[:-2] + (-1,))[..., :p]
+    kk = jnp.asarray(k, jnp.int32)
+    states = []
+    for s in range(S):
+        g, j, s_loc = ((), s, S) if group_size is None else (
+            (s // group_size,), s % group_size, group_size)
+        nd = flat(host["nodes"][g][j * n:(j + 1) * n])
+        rho2 = host["rho2"][g]
+        r0, b0, el = j * e_a, (s_loc + j) * e_a, e_a_lane[s]
+        states.append(RFASTState(
+            k=kk, x=nd[:, 0], v=nd[:, 1], z=nd[:, 2], g_prev=nd[:, 3],
+            rho=flat(rho2[r0:r0 + el]), rho_buf=flat(rho2[b0:b0 + el]),
+            v_hist=flat(host["v_hist"][g][:, j * n:(j + 1) * n]),
+            rho_hist=flat(host["rho_hist"][g][:, r0:r0 + el])))
+    return states
+
+
+def _iterates(packed: PackedState, s: int, *, n: int, p: int,
+              group_size=None) -> jnp.ndarray:
+    """Lane ``s``'s node iterates x as ``(n, p)`` — what ``eval_fn``
+    reads; nothing else of the packed state is touched."""
+    if group_size is None:
+        index = ((s * n, (s + 1) * n), 0)
+    else:
+        j = s % group_size
+        index = (s // group_size, (j * n, (j + 1) * n), 0)
+    return _take(packed.nodes, index, p)
+
+
+def _init_packed(grad_fn: GradFn, x0: jnp.ndarray, init_keys: jnp.ndarray,
+                 **layout) -> PackedState:
+    """The paper init of S lanes — z = g_prev = ∇f_i(x_i^0; ζ_i^0) from
+    each lane's init key, v = ρ = ρ̃ = histories = 0 — written straight
+    into the lane-dense packed layout by the :func:`_init_programs`.
+
+    ``x0`` is ``(p,)``, ``(n, p)`` or ``(S', n, p)`` with ``S' <= S``
+    (missing lanes repeat the last one); ``init_keys`` is ``(S, 2)``."""
+    x0 = jnp.asarray(x0, jnp.float32)
+    grads, assemble = _init_programs(grad_fn, x0.ndim, p=x0.shape[-1],
+                                     **layout)
+    return assemble(x0, grads(x0, init_keys))
+
+
+def _init_programs(grad_fn: GradFn, x0_ndim: int, *, p: int, S: int, n: int,
+                   H: int, e_a: int, p_pad: int, groups: int | None = None,
+                   sharding_of=None):
+    """The two jitted init programs: ``grads(x0, init_keys)`` -> the
+    lane-dense ``(S, n, R, LANE)`` initial gradients, and
+    ``assemble(x0, g0)`` -> the :class:`PackedState`.
+
+    Two programs, not one: XLA:TPU takes minutes to compile the flat ->
+    lane-dense relayout when it shares a program with the gradient, and
+    seconds when it does not.  x0 is broadcast inside the programs,
+    never tiled on the host, and every large array is an argument or
+    built in a program (none is captured as a constant).  ``groups``
+    selects the mesh engine's group-stacked layout and ``sharding_of``
+    (``leaf -> Sharding``) places the state on the mesh as it is
+    produced: no device ever holds more than its shard of it."""
+    row = (p_pad // LANE, LANE)
+    glead = () if groups is None else (groups,)
+    s_loc = S if groups is None else S // groups
+    shapes = PackedState(nodes=glead + (s_loc * n, 4) + row,
+                         rho2=glead + (2 * s_loc * e_a,) + row,
+                         v_hist=glead + (H, s_loc * n) + row,
+                         rho_hist=glead + (H, s_loc * e_a) + row)
+
+    def lanes_of(x, tail):
+        """x0 (or its lane-dense form) -> (S, n, *tail) per-lane rows."""
+        x = x.reshape((1,) * (3 - x0_ndim) + x.shape)
+        x = jnp.broadcast_to(x, (x.shape[0], n) + tail)
+        if x.shape[0] == S:
+            return x
+        return jnp.concatenate(
+            [x, jnp.broadcast_to(x[-1:], (S - x.shape[0], n) + tail)])
+
+    def grads(x0, keys):
+        # one node at a time, each gradient written out lane-dense: a
+        # batched (S·n, p) gradient is another relayout XLA:TPU compiles
+        # for minutes
+        node_keys = jax.vmap(lambda k: jax.random.split(k, n))(keys)
+        x = lanes_of(x0, (p,)).reshape(S * n, p)
+        g = jax.lax.map(
+            lambda a: _to_lanes(grad_fn(a[0], a[1], a[2]), p_pad),
+            (jnp.tile(jnp.arange(n), S), x, node_keys.reshape(S * n, 2)))
+        return g.reshape((S, n) + row)
+
+    def assemble(x0, gl):
+        xl = lanes_of(_to_lanes(x0, p_pad), row)
+        nodes = jnp.stack([xl, jnp.zeros_like(xl), gl, gl], axis=2)
+        return PackedState(
+            nodes=nodes.reshape(shapes.nodes),
+            rho2=jnp.zeros(shapes.rho2, jnp.float32),
+            v_hist=jnp.zeros(shapes.v_hist, jnp.float32),
+            rho_hist=jnp.zeros(shapes.rho_hist, jnp.float32))
+
+    shardings = None if sharding_of is None else PackedState(
+        *(sharding_of(jax.ShapeDtypeStruct(sh, jnp.float32))
+          for sh in shapes))
+    return jax.jit(grads), jax.jit(assemble, out_shardings=shardings)
 
 
 class _WaveInputs(NamedTuple):
@@ -293,59 +510,6 @@ class _WaveInputs(NamedTuple):
     rho_gidx: jnp.ndarray   # (B, ko+ka)
     out_wt: jnp.ndarray     # (B, ko)
     keys: jnp.ndarray       # (B, 2)
-
-
-def pack_state(state: RFASTState, *, e_a: int | None = None,
-               p_pad: int | None = None) -> PackedState:
-    """Device layout for the wavefront/sweep engines.
-
-    ``e_a`` pads the ρ state to a larger flat layout (fleet sweeps
-    normalize every lane to the fleet-wide max A-edge count; the extra
-    zero rows are never referenced by a real lane and the matching
-    WavefrontPlan must be built/padded against the same ``e_a``).
-
-    ``p_pad`` zero-pads the flat parameter axis (the compiled grid
-    kernel needs block-multiple widths; the zero tail is inert under the
-    linear protocol — pass the real ``p`` back via the engines'
-    ``p_real`` / :func:`unpack_state`'s ``p``).
-    """
-    rho, rho_buf, rho_hist = state.rho, state.rho_buf, state.rho_hist
-    if e_a is not None and e_a != rho.shape[0]:
-        if e_a < rho.shape[0]:
-            raise ValueError(f"e_a={e_a} < state's A-edge count "
-                             f"{rho.shape[0]}")
-        pad = e_a - rho.shape[0]
-        rho = jnp.pad(rho, ((0, pad), (0, 0)))
-        rho_buf = jnp.pad(rho_buf, ((0, pad), (0, 0)))
-        rho_hist = jnp.pad(rho_hist, ((0, 0), (0, pad), (0, 0)))
-    packed = PackedState(
-        nodes=jnp.stack([state.x, state.v, state.z, state.g_prev], axis=1),
-        rho2=jnp.concatenate([rho, rho_buf], axis=0),
-        v_hist=state.v_hist,
-        rho_hist=rho_hist,
-    )
-    p = packed.nodes.shape[-1]
-    if p_pad is not None and p_pad != p:
-        if p_pad < p:
-            raise ValueError(f"p_pad={p_pad} < state's p={p}")
-        wpad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1)
-                                 + [(0, p_pad - p)])
-        packed = PackedState(*(wpad(a) for a in packed))
-    return packed
-
-
-def unpack_state(packed: PackedState, k, *, p: int | None = None
-                 ) -> RFASTState:
-    e_a = packed.rho_hist.shape[1]
-    if p is not None and p != packed.nodes.shape[-1]:
-        packed = PackedState(*(a[..., :p] for a in packed))
-    return RFASTState(
-        k=jnp.asarray(k, jnp.int32),
-        x=packed.nodes[:, 0], v=packed.nodes[:, 1],
-        z=packed.nodes[:, 2], g_prev=packed.nodes[:, 3],
-        rho=packed.rho2[:e_a], rho_buf=packed.rho2[e_a:],
-        v_hist=packed.v_hist, rho_hist=packed.rho_hist,
-    )
 
 
 def _wave_step(
@@ -368,82 +532,96 @@ def _wave_step(
 
     ``impl="pallas"`` routes the S.2b/c + S.4 commit math (the
     bandwidth-bound tail) through ONE fused :func:`commit_grid` launch
-    for the whole wave — the lane tables become flat-row gather indices
-    into the packed state (``nodes.reshape(N·4, p)``,
-    ``rho_hist.reshape(H·E, p)``, ``rho2``), so no per-lane neighbour
-    stacks are materialized and no per-lane kernel is dispatched.
-    ``mode`` is the resolved dispatch mode: ``interpret`` keeps the
-    original vmapped per-node kernel as the bit-faithful oracle;
-    ``compiled``/``emulate`` take the grid.  The consensus pull stays in
-    jnp either way: the gradient must be sampled at the mixed point x⁺
-    before the commit runs.
+    for the whole wave — the lane tables become row gather indices into
+    the lane-dense packed state (``nodes.reshape(N·4, R, LANE)``,
+    ``rho_hist.reshape(H·E, R, LANE)``, ``rho2``), so no per-lane
+    neighbour stacks are materialized and no per-lane kernel is
+    dispatched.  ``mode`` is the resolved dispatch mode: ``interpret``
+    keeps the original vmapped per-node kernel as the bit-faithful
+    oracle; ``compiled``/``emulate`` take the grid.  The consensus pull
+    stays in jnp either way: the gradient must be sampled at the mixed
+    point x⁺ before the commit runs.
 
-    ``p_real`` (< p only when the flat axis was block-padded for the
-    compiled grid) slices the parameter tail off before ``grad_fn`` and
-    zero-pads the gradient back — the pad tail stays exactly zero under
-    the linear protocol.
+    ``grad_fn`` sees the flat ``(p,)`` iterate; ``p_real`` (< the padded
+    width) slices the parameter tail off before the call and zero-pads
+    the gradient back — the pad tail stays exactly zero under the linear
+    protocol.
     """
-    node_rows = state.nodes[w.agent]                       # (B, 4, p)
+    # per-lane scalars broadcast over the trailing (R, LANE) axes
+    bc = lambda a: a[..., None, None]
+    node_rows = state.nodes[w.agent]                       # (B, 4, R, L)
     x_l, z_l, gp_l = node_rows[:, 0], node_rows[:, 2], node_rows[:, 3]
 
     # (S.1) local descent -------------------------------------------------
-    v_new = descent_step(x_l, z_l, gamma)                  # (B, p)
+    v_new = descent_step(x_l, z_l, gamma)                  # (B, R, L)
 
     # (S.2a) consensus pull, reads resolved to delta-history rows ----------
-    vals_v = state.v_hist[w.rslot_v, w.src_v]              # (B, kw, p)
-    x_a = consensus_mix(w.w_self[:, None], v_new,
-                        w.w_in.swapaxes(0, 1)[..., None],
+    vals_v = state.v_hist[w.rslot_v, w.src_v]              # (B, kw, R, L)
+    # order the v_hist write below after this read: nothing else does,
+    # and XLA would otherwise keep a whole copy of the ring to serve it
+    vals_v, v_hist = jax.lax.optimization_barrier((vals_v, state.v_hist))
+    x_a = consensus_mix(bc(w.w_self), v_new, bc(w.w_in.swapaxes(0, 1)),
                         vals_v.swapaxes(0, 1))             # sum over kw
 
     # (S.2b) robust gradient tracking -------------------------------------
-    p = x_a.shape[-1]
+    # flat for the gradient and back; both sides of each relayout are
+    # materialized (see _init_programs: fused, it compiles for minutes)
+    B = x_a.shape[0]
+    x_flat = jax.lax.optimization_barrier(x_a.reshape(B, -1))
+    p = x_flat.shape[-1]
     if p_real is not None and p_real != p:
-        g_new = jax.vmap(grad_fn)(w.agent, x_a[:, :p_real], w.keys)
+        g_new = jax.vmap(grad_fn)(w.agent, x_flat[:, :p_real], w.keys)
         g_new = jnp.pad(g_new, ((0, 0), (0, p - p_real)))
     else:
-        g_new = jax.vmap(grad_fn)(w.agent, x_a, w.keys)
+        g_new = jax.vmap(grad_fn)(w.agent, x_flat, w.keys)
+    g_new = jax.lax.optimization_barrier(
+        jax.lax.optimization_barrier(g_new).reshape(x_a.shape))
 
     if impl == "pallas" and mode != "interpret":
         # one fused launch for the whole wave: gather tables over the
-        # flat state rows.  The kernel's masked ρ̃ blend equals the jnp
+        # state rows.  The kernel's masked ρ̃ blend equals the jnp
         # path's unconditional vals_rho commit: a_val is a 0/1 indicator
         # and zero-mask rows scatter to the drop sentinel anyway.
         # Sentinel lanes clamp inside commit_grid; their commits drop.
-        nodes_flat = state.nodes.reshape(-1, p)            # (N·4, p)
-        hist_flat = state.rho_hist.reshape(-1, p)          # (H·E, p)
+        rows = lambda a: a.reshape((-1,) + a.shape[-2:])
+        nodes_flat = rows(state.nodes)                     # (N·4, R, L)
         idx_z, idx_g, idx_ri, idx_rb, idx_ro = grid_gather_tables(
             w.agent, w.rslot_rho, w.hist_epos, w.rho_gidx,
             e_a_flat=state.rho_hist.shape[1], ko=ko)
         z_a, rho_new, buf_new = commit_grid(
             idx_z, idx_g, idx_ri, idx_rb, idx_ro,
             w.a_self, w.a_val, w.out_wt,
-            nodes_flat, g_new, nodes_flat, hist_flat,
+            nodes_flat, g_new, nodes_flat, rows(state.rho_hist),
             state.rho2, state.rho2, mode=mode)
         rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
     elif impl == "pallas":
-        # interpret-mode oracle: the original vmapped per-node kernel.
-        vals_rho = state.rho_hist[w.rslot_rho, w.hist_epos]  # (B, ka, p)
-        rho_rows = state.rho2[w.rho_gidx]                    # (B, ko+ka, p)
+        # interpret-mode oracle: the original vmapped per-node kernel
+        # over flat lanes.
+        vals_rho = state.rho_hist[w.rslot_rho, w.hist_epos]  # (B, ka, R, L)
+        rho_rows = state.rho2[w.rho_gidx]                    # (B, ko+ka, ..)
+        flat = lambda a: a.reshape(a.shape[:-2] + (-1,))
 
         def one_lane(z_, gn_, go_, ri_, rb_, mk_, ro_, ao_, as_):
             return rfast_commit(z_, gn_, go_, ri_, rb_, mk_, ro_, ao_,
                                 a_self=as_, impl="pallas",
                                 interpret=True)
         z_a, rho_new, buf_new = jax.vmap(one_lane)(
-            z_l, g_new, gp_l, vals_rho, rho_rows[:, ko:], w.a_val,
-            rho_rows[:, :ko], w.out_wt, w.a_self)
+            flat(z_l), flat(g_new), flat(gp_l), flat(vals_rho),
+            flat(rho_rows[:, ko:]), w.a_val, flat(rho_rows[:, :ko]),
+            w.out_wt, w.a_self)
+        lanes = lambda a: a.reshape(a.shape[:-1] + x_a.shape[-2:])
+        z_a, rho_new, buf_new = lanes(z_a), lanes(rho_new), lanes(buf_new)
         rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
     else:
-        vals_rho = state.rho_hist[w.rslot_rho, w.hist_epos]  # (B, ka, p)
-        rho_rows = state.rho2[w.rho_gidx]                    # (B, ko+ka, p)
-        recv = jnp.sum(w.a_val[..., None]
-                       * (vals_rho - rho_rows[:, ko:]), axis=1)
+        vals_rho = state.rho_hist[w.rslot_rho, w.hist_epos]  # (B, ka, R, L)
+        rho_rows = state.rho2[w.rho_gidx]                    # (B, ko+ka, ..)
+        recv = jnp.sum(bc(w.a_val) * (vals_rho - rho_rows[:, ko:]), axis=1)
         z_half = tracking_step(z_l, recv, g_new, gp_l)
 
         # (S.2c) keep own share; push mass onto out-edges ------------------
-        z_a = w.a_self[:, None] * z_half
+        z_a = bc(w.a_self) * z_half
         rho_new = rho_rows[:, :ko] \
-            + w.out_wt[..., None] * z_half[:, None, :]     # (B, ko, p)
+            + bc(w.out_wt) * z_half[:, None]              # (B, ko, R, L)
         rho_commit = jnp.concatenate([rho_new, vals_rho], axis=1)
 
     # commit: disjoint row scatters; (S.4) ρ̃ rows take the consumed values
@@ -451,7 +629,7 @@ def _wave_step(
     return PackedState(
         nodes=state.nodes.at[w.agent].set(node_new, mode="drop"),
         rho2=state.rho2.at[w.rho_gidx].set(rho_commit, mode="drop"),
-        v_hist=state.v_hist.at[w.wslot, w.agent].set(v_new, mode="drop"),
+        v_hist=v_hist.at[w.wslot, w.agent].set(v_new, mode="drop"),
         rho_hist=state.rho_hist.at[w.wslot[:, None], w.rho_gidx[:, :ko]]
         .set(rho_new, mode="drop"),
     ), None
@@ -574,19 +752,19 @@ def _mesh_sweep_scan(
     p_real: int | None = None,
 ):
     """Mesh-mapped fleet engine: :func:`rfast_sweep_scan` distributed over
-    a device mesh via the :func:`~repro.core.runtime_sharded._shard_map`
-    compat shim.
+    a device mesh by ``jax.shard_map``.
 
     Layout (see :func:`~repro.core.runtime_sharded.packed_sweep_specs`):
     the packed state and wave tables carry a leading *lane-group* axis —
     one block of ``S_loc`` consecutive lanes per ``lane_axis`` device —
-    and the flat parameter axis is split over ``param_axis``.  Inside the
+    and the lane-dense parameter rows (the ``R`` axis) are split over
+    ``param_axis``.  Inside the
     region each device runs the unmodified :func:`_wave_step` scan over
     its own group's flattened program, so lane groups never communicate:
     lane parallelism is embarrassingly parallel by construction.
 
     When ``param_axis`` has size M > 1 every state array holds only its
-    ``p_loc = p_pad // M`` slice of the flat axis.  The protocol math is
+    ``p_loc = p_pad // M`` slice of the flat axis (``R // M`` rows).  The protocol math is
     linear and elementwise along p, so it runs unchanged on slices; only
     the gradient needs the full iterate, which is reconstructed per wave
     by ONE tiled ``all_gather`` over ``param_axis`` (O(p) per lane — the
@@ -649,8 +827,10 @@ def _mesh_sweep_scan(
     def run_waves(state: PackedState, waves: _WaveInputs):
         st_specs = jax.tree.map(st_spec, state)
         wv_specs = jax.tree.map(wv_spec, waves)
-        fn = _shard_map(local_run, mesh, (st_specs, wv_specs), st_specs,
-                        axes)
+        fn = jax.shard_map(local_run, mesh=mesh,
+                           in_specs=(st_specs, wv_specs),
+                           out_specs=st_specs, axis_names=set(axes),
+                           check_vma=False)
         return fn(state, waves)
 
     return jax.jit(run_waves, donate_argnums=(0,) if donate else ())
@@ -684,7 +864,7 @@ def run_rfast(
     *,
     seed: int = 0,
     eval_every: int = 0,
-    eval_fn: Callable[[RFASTState, float], dict] | None = None,
+    eval_fn: Callable[[jnp.ndarray, float], dict] | None = None,
     mode: str = "wavefront",
     impl: str = "jnp",
     interpret: bool | None = None,
@@ -721,13 +901,17 @@ def run_rfast(
 
     ``interpret`` (pallas only) is the tri-state dispatch override:
     None autodetects (compiled grid launch on TPU, jnp emulation of the
-    grid elsewhere); True forces the interpreter oracle.  In compiled
-    mode the flat parameter axis is transparently block-padded for the
-    kernel and stripped again before ``grad_fn``/``eval_fn``/return.
+    grid elsewhere); True forces the interpreter oracle.  The wavefront
+    engine keeps the flat axis lane-dense and padded (to whole kernel
+    blocks in compiled mode) and strips it again before
+    ``grad_fn``/``eval_fn``/return.
 
-    Both modes donate the running state between chunks (in-place
-    updates): ``eval_fn`` must extract what it needs (floats/arrays of
-    its own) rather than retain the state object it is handed.
+    ``eval_fn(x, t)`` receives the ``(n, p)`` node iterates at virtual
+    time ``t`` — the only rows of the state an evaluation reads, so the
+    rest is never copied out of the packed layout.  Both modes donate
+    the running state between chunks (in-place updates).  The wavefront
+    engine returns its final state on the host (NumPy arrays), moved
+    off the device array by array.
 
     ``verify_plans=True`` runs the :mod:`repro.analysis.planlint` pass
     over the CommPlan and compiled WavefrontPlan before anything is
@@ -751,10 +935,8 @@ def run_rfast(
     if eval_every <= 0:
         eval_every = K
 
-    if state0 is None:
-        state = init_state(plan, x0, grad_fn, init_key, H)
-        k0 = 0
-    else:
+    k0 = 0
+    if state0 is not None:
         if state0.v_hist.shape[0] != H:
             raise ValueError(
                 f"state0 has H={state0.v_hist.shape[0]} but this schedule "
@@ -764,12 +946,13 @@ def run_rfast(
         if k0 < K and k0 % eval_every != 0:
             raise ValueError(f"state0.k={k0} is not an eval-chunk boundary "
                              f"(eval_every={eval_every})")
-        # copy: the engines donate their state buffers in place
-        state = jax.tree.map(jnp.array, state0)
-    if k0 >= K:
-        return state, metrics
+        if k0 >= K:
+            return jax.tree.map(jnp.array, state0), metrics
 
     if mode == "event":
+        # copy state0: the engines donate their state buffers in place
+        state = (init_state(plan, x0, grad_fn, init_key, H) if state0 is None
+                 else jax.tree.map(jnp.array, state0))
         if verify_plans:
             from ..analysis import planlint
             planlint.check_or_raise(
@@ -785,20 +968,19 @@ def run_rfast(
             state = chunk(state, agent[s:e], stamp_v[s:e], stamp_rho[s:e],
                           step_keys[s:e])
             if eval_fn is not None:
-                m = eval_fn(state, float(schedule.times[e - 1]))
+                m = eval_fn(state.x, float(schedule.times[e - 1]))
                 m["k"] = e
                 metrics.append(m)
             if chunk_cb is not None:
                 chunk_cb(state, e)       # event engine tracks k == e itself
         return state, metrics
 
-    # compiled grid launches need a block-multiple flat width: pad the
-    # parameter axis once up front (the zero tail is provably inert) and
-    # strip it at every unpack below
-    p = int(state.x.shape[-1])
-    p_pad = p
-    if impl == "pallas" and dispatch.resolve_mode(interpret) == "compiled":
-        p_pad = block_pad_width(p)
+    # lane-dense packed state, block-padded for compiled grid launches
+    # (the zero tail is provably inert); stripped again at every read
+    p = int(jnp.shape(x0)[-1] if state0 is None else state0.x.shape[-1])
+    p_pad = _pad_width(p, blocks=(impl == "pallas" and
+                                  dispatch.resolve_mode(interpret)
+                                  == "compiled"))
 
     wf = build_wavefront_plan(schedule, plan, H, break_every=eval_every)
     if verify_plans:
@@ -813,7 +995,11 @@ def run_rfast(
         plan, grad_fn, gamma, donate=True, impl=impl, interpret=interpret,
         p_real=(p if p_pad != p else None))
     waves = wave_inputs(wf, step_keys)
-    packed = pack_state(state, p_pad=(p_pad if p_pad != p else None))
+    if state0 is None:
+        packed = _init_packed(grad_fn, x0, init_key[None], S=1, n=plan.n,
+                              H=H, e_a=max(1, plan.n_edges_a), p_pad=p_pad)
+    else:
+        packed = pack_state(state0, p_pad=p_pad)
 
     # chunk boundaries in wave space (waves never cross eval boundaries);
     # pad every chunk to the max wave count so the runner compiles once
@@ -845,40 +1031,20 @@ def run_rfast(
         packed = runner(packed, chunk_waves)
         e = min(K, (ci + 1) * eval_every)
         if eval_fn is not None:
-            m = eval_fn(unpack_state(packed, e, p=p),
+            m = eval_fn(_iterates(packed, 0, n=plan.n, p=p),
                         float(schedule.times[e - 1]))
             m["k"] = e
             metrics.append(m)
         if chunk_cb is not None:
             chunk_cb(unpack_state(packed, e, p=p), e)
-    return unpack_state(packed, K, p=p), metrics
+    return _lane_states(packed, K, S=1, n=plan.n,
+                        e_a=packed.rho_hist.shape[1], p=p,
+                        consume=True)[0], metrics
 
 
 # --------------------------------------------------------------------- #
 # fleet sweeps: many experiments as one compiled wavefront program
 # --------------------------------------------------------------------- #
-def _lane_state(packed: PackedState, s: int, k: int, *, S: int, n: int,
-                e_a: int, e_a_lane: int,
-                p: int | None = None) -> RFASTState:
-    """Slice fleet lane ``s`` out of the flat fleet state (lane blocks:
-    nodes ``[s·n, (s+1)·n)``, ρ ``[s·e_a, ·)`` with ρ̃ at offset
-    ``S·e_a``) and strip its ρ state back to the lane's real A-edge
-    count (the fleet layout pads every lane to the max).  ``p`` strips a
-    block-padded flat axis back to the real dimension."""
-    if p is not None and p != packed.nodes.shape[-1]:
-        packed = PackedState(*(a[..., :p] for a in packed))
-    nd = packed.nodes[s * n:(s + 1) * n]
-    rho = packed.rho2[s * e_a:s * e_a + e_a_lane]
-    rho_buf = packed.rho2[(S + s) * e_a:(S + s) * e_a + e_a_lane]
-    return RFASTState(
-        k=jnp.asarray(k, jnp.int32),
-        x=nd[:, 0], v=nd[:, 1], z=nd[:, 2], g_prev=nd[:, 3],
-        rho=rho, rho_buf=rho_buf,
-        v_hist=packed.v_hist[:, s * n:(s + 1) * n],
-        rho_hist=packed.rho_hist[:, s * e_a:s * e_a + e_a_lane],
-    )
-
-
 def run_sweep(
     topos,
     schedules,
@@ -888,7 +1054,7 @@ def run_sweep(
     *,
     seeds=None,
     eval_every: int = 0,
-    eval_fn: Callable[[RFASTState, float], dict] | None = None,
+    eval_fn: Callable[[jnp.ndarray, float], dict] | None = None,
     impl: str = "jnp",
     interpret: bool | None = None,
     verify_plans: bool = False,
@@ -935,16 +1101,16 @@ def run_sweep(
         axis size by replicating the last lane; replica results are
         dropped) and the flat parameter axis is sharded over
         ``param_axis`` when that axis has size > 1, so p >= 100M states
-        fit in per-device memory.  Per lane the results match the
-        unsharded engine to fp32 tolerance (tested).  ``None`` (default)
-        keeps the single-device path bit-for-bit unchanged.
+        fit in per-device memory.  The initial state is built straight
+        into that sharding.  Per lane the results match the unsharded
+        engine to fp32 tolerance (tested).
       lane_axis / param_axis: mesh axis names (``"data"`` / ``"model"``,
         the :func:`repro.launch.mesh.make_sweep_mesh` convention).
 
     Returns:
       ``(states, metrics)`` — the final per-lane :class:`RFASTState` list
-      (ρ state stripped back to each lane's real A-edge count) and the
-      per-lane metrics lists.
+      on the host (ρ state stripped back to each lane's real A-edge
+      count) and the per-lane metrics lists.
     """
     schedules = list(schedules)
     S = len(schedules)
@@ -996,65 +1162,23 @@ def run_sweep(
     e_a = max(max(1, pl.n_edges_a) for pl in plans)
     padded_plans = [pad_comm_plan(pl, kw=kw, ka=ka, ko=ko) for pl in plans]
 
-    # per-lane RNG streams, derived exactly as run_rfast does
     x0 = jnp.asarray(x0, jnp.float32)
-    if x0.ndim == 1:
-        x0 = jnp.tile(x0[None, :], (n, 1))
     if x0.ndim == 3 and x0.shape[0] != S:
         raise ValueError(f"per-lane x0 has {x0.shape[0]} lanes, "
                          f"expected {S}")
-    x0_lanes = (x0 if x0.ndim == 3
-                else jnp.broadcast_to(x0[None], (S,) + x0.shape))
-    if S_pad != S:
-        x0_lanes = jnp.concatenate(
-            [x0_lanes, jnp.broadcast_to(x0_lanes[-1:],
-                                        (S_pad - S,) + x0_lanes.shape[1:])])
-    p = int(x0_lanes.shape[-1])
-    # compiled grid launches need block-multiple widths (inert zero
-    # tail); a sharded param axis additionally needs p_pad % M == 0 so
-    # every device holds an equal p_loc slice
-    p_pad = p
-    if impl == "pallas" and dispatch.resolve_mode(interpret) == "compiled":
-        p_pad = block_pad_width(p, M)
-    elif M > 1:
-        p_pad = -(-p // M) * M
+    p = int(x0.shape[-1])
+    # lane-dense flat axis: whole LANE rows per param shard, whole grid
+    # blocks for compiled launches (the zero tail is inert)
+    p_pad = _pad_width(p, M, blocks=(impl == "pallas" and
+                                     dispatch.resolve_mode(interpret)
+                                     == "compiled"))
+    # per-lane RNG streams, derived exactly as run_rfast does
     lane_keys, init_keys = [], []
     for s in range(S_pad):
         key, init_key = jax.random.split(jax.random.PRNGKey(seeds[s]))
         lane_keys.append(jax.random.split(key, K))
         init_keys.append(init_key)
     step_keys = jnp.stack(lane_keys)                        # (S_pad, K, 2)
-
-    # fleet init (the paper init per lane: z = g_prev = ∇f(x0; ζ0) from
-    # the lane's init key, v = ρ = ρ̃ = hist = 0) — lane s's g0 is
-    # op-identical to init_state's, so the trajectories match the
-    # per-lane runs.  Deliberately NOT jitted: a jit here would compile
-    # the gradient graph a second time (the scan body below already
-    # pays for it), doubling the fleet's one-time cost.  Layout: the
-    # flat fleet state of flatten_plans (lane blocks on node/edge axes).
-    node_keys = jax.vmap(lambda k: jax.random.split(k, n))(
-        jnp.stack(init_keys))
-    g0 = jax.vmap(
-        lambda x, ks: jax.vmap(grad_fn)(jnp.arange(n), x, ks)
-    )(x0_lanes, node_keys)
-    nodes = jnp.stack([x0_lanes, jnp.zeros_like(x0_lanes), g0, g0],
-                      axis=2)
-    if p_pad != p:
-        nodes = jnp.pad(nodes, ((0, 0), (0, 0), (0, 0), (0, p_pad - p)))
-    z = lambda *s_: jnp.zeros(s_, jnp.float32)
-    if mesh is None:
-        packed = PackedState(nodes=nodes.reshape(S_pad * n, 4, p_pad),
-                             rho2=z(2 * S_pad * e_a, p_pad),
-                             v_hist=z(H, S_pad * n, p_pad),
-                             rho_hist=z(H, S_pad * e_a, p_pad))
-    else:
-        # group-stacked layout: each device's block is the flat fleet
-        # state of ITS OWN S_loc lanes, so per-group plans flatten with
-        # group-local offsets and no cross-group indices exist
-        packed = PackedState(nodes=nodes.reshape(D, S_loc * n, 4, p_pad),
-                             rho2=z(D, 2 * S_loc * e_a, p_pad),
-                             v_hist=z(D, H, S_loc * n, p_pad),
-                             rho_hist=z(D, H, S_loc * e_a, p_pad))
 
     # per-lane plans, then chunk-aligned fleet stacking: chunk c of every
     # lane is padded to the fleet-wide max chunk wave count, so chunk c
@@ -1115,22 +1239,20 @@ def run_sweep(
             impl=impl, interpret=interpret,
             p_real=(p if p_pad != p else None))
         st_sh, wv_sh = sweep_mesh_shardings(mesh, lane_axis, param_axis)
-        packed = jax.device_put(packed, jax.tree.map(st_sh, packed))
         waves = jax.device_put(waves, jax.tree.map(wv_sh, waves))
     if verify_plans:
         planlint.check_or_raise(diags, "run_sweep(verify_plans)")
 
-    def lane_state(pk, s, k):
-        if mesh is None:
-            return _lane_state(pk, s, k, S=S_pad, n=n, e_a=e_a,
-                               e_a_lane=e_a_lane[s], p=p)
-        g, j = divmod(s, S_loc)
-        grp = jax.tree.map(lambda a: a[g], pk)
-        return _lane_state(grp, j, k, S=S_loc, n=n, e_a=e_a,
-                           e_a_lane=e_a_lane[s], p=p)
+    # the fleet init straight into the engine's layout (and, on a mesh,
+    # its sharding): lane s's g0 comes from the lane's init key exactly
+    # as in run_rfast, so the trajectories match the per-lane runs
+    group_size = None if mesh is None else S_loc
+    packed = _init_packed(grad_fn, x0, jnp.stack(init_keys), S=S_pad,
+                          n=n, H=H, e_a=e_a, p_pad=p_pad,
+                          groups=None if mesh is None else D,
+                          sharding_of=None if mesh is None else st_sh)
 
     metrics: list[list[dict]] = [[] for _ in range(S)]
-    e_a_lane = [max(1, pl.n_edges_a) for pl in plans]
     for ci in range(len(chunk_starts)):
         sl = (lambda a: a[:, ci * cmax:(ci + 1) * cmax]) if mesh is not \
             None else (lambda a: a[ci * cmax:(ci + 1) * cmax])
@@ -1138,12 +1260,16 @@ def run_sweep(
         e = min(K, (ci + 1) * eval_every)
         if eval_fn is not None:
             for s in range(S):
-                m = eval_fn(lane_state(packed, s, e),
+                m = eval_fn(_iterates(packed, s, n=n, p=p,
+                                      group_size=group_size),
                             float(schedules[s].times[e - 1]))
                 m["k"] = e
                 metrics[s].append(m)
-    states = [lane_state(packed, s, K) for s in range(S)]
-    return states, metrics
+    states = _lane_states(
+        packed, K, S=S_pad, n=n, e_a=e_a, p=p,
+        e_a_lane=[max(1, pl.n_edges_a) for pl in plans],
+        group_size=group_size, consume=True)
+    return states[:S], metrics
 
 
 # --------------------------------------------------------------------- #
@@ -1181,6 +1307,7 @@ def migrate_state(state: RFASTState, prev_topo, epoch, *,
     NEW epoch's ρ layout and ``H``-deep rings, ``k = 0`` (epoch-local;
     callers track the global event count).
     """
+    state = jax.tree.map(jnp.asarray, state)     # host states welcome
     prev_plan = as_comm_plan(prev_topo)
     new_plan = as_comm_plan(epoch.topology)
     n, p = state.x.shape
@@ -1250,14 +1377,12 @@ def _scan_epochs(epochs, plans, wfs, bounds, runner, step_keys, state0,
     each epoch's chunks (padded to the shared ``(cmax, B)`` wave shape),
     migrating the packed state at every epoch boundary."""
     metrics: list[dict] = []
-    packed = pack_state(state0, e_a=e_a,
-                        p_pad=(p_pad if p_pad != p else None))
+    packed = pack_state(state0, e_a=e_a, p_pad=p_pad)
     for i, (ep, wf, b) in enumerate(zip(epochs, wfs, bounds)):
         if i > 0:
             state = unpack_state(packed, ep.k0, p=p)
             state = migrate_state(state, epochs[i - 1].topology, ep, H=H)
-            packed = pack_state(state, e_a=e_a,
-                                p_pad=(p_pad if p_pad != p else None))
+            packed = pack_state(state, e_a=e_a, p_pad=p_pad)
         rc = concat_plans(
             [pad_plan(slice_plan(wf, b[c], b[c + 1]),
                       width=B, n_waves=cmax, e_a=e_a)
@@ -1271,7 +1396,8 @@ def _scan_epochs(epochs, plans, wfs, bounds, runner, step_keys, state0,
             e_loc = min(ep.K, (ci + 1) * eval_every)
             kg = ep.k0 + e_loc
             if eval_fn is not None:
-                m = eval_fn(unpack_state(packed, kg, p=p),
+                m = eval_fn(_iterates(packed, 0, n=packed.nodes.shape[0],
+                                      p=p),
                             ep.t0 + float(sched.times[e_loc - 1]))
                 m["k"] = kg
                 metrics.append(m)
@@ -1296,7 +1422,7 @@ def run_epochs(
     *,
     seed: int = 0,
     eval_every: int = 0,
-    eval_fn: Callable[[RFASTState, float], dict] | None = None,
+    eval_fn: Callable[[jnp.ndarray, float], dict] | None = None,
     impl: str = "jnp",
     interpret: bool | None = None,
     chunk_cb: Callable[[RFASTState, int], None] | None = None,
@@ -1357,9 +1483,9 @@ def run_epochs(
     step_keys = jax.random.split(key, K)
     state0 = init_state(plans[0], x0, grad_fn, init_key, H)
     p = int(state0.x.shape[-1])
-    p_pad = p
-    if impl == "pallas" and dispatch.resolve_mode(interpret) == "compiled":
-        p_pad = block_pad_width(p)
+    p_pad = _pad_width(p, blocks=(impl == "pallas" and
+                                  dispatch.resolve_mode(interpret)
+                                  == "compiled"))
     runner = rfast_wavefront_scan(
         padded[0], grad_fn, gamma, donate=True, impl=impl,
         interpret=interpret, p_real=(p if p_pad != p else None))
@@ -1377,7 +1503,7 @@ def run_sweep_epochs(
     *,
     seeds=None,
     eval_every: int = 0,
-    eval_fn: Callable[[RFASTState, float], dict] | None = None,
+    eval_fn: Callable[[jnp.ndarray, float], dict] | None = None,
     impl: str = "jnp",
     interpret: bool | None = None,
     verify_plans: bool = False,
@@ -1463,11 +1589,9 @@ def run_sweep_epochs(
                 f"mesh's {lane_axis!r} axis must have size 1 "
                 "(lane-parallel meshes go through run_sweep)")
         M = _mesh_axis_size(mesh, param_axis)
-    p_pad = p
-    if impl == "pallas" and dispatch.resolve_mode(interpret) == "compiled":
-        p_pad = block_pad_width(p, M)
-    elif M > 1:
-        p_pad = -(-p // M) * M
+    p_pad = _pad_width(p, M, blocks=(impl == "pallas" and
+                                     dispatch.resolve_mode(interpret)
+                                     == "compiled"))
     if mesh is None:
         runner = rfast_wavefront_scan(
             lanes[0][1][0], grad_fn, gamma, donate=True, impl=impl,
@@ -1500,7 +1624,7 @@ def run_sweep_epochs(
         step_keys = jax.random.split(key, int(trace.K))
         state0 = init_state(plans[0], x0_lanes[s], grad_fn, init_key, H)
         lane_eval = (None if eval_fn is None
-                     else lambda st, t: dict(eval_fn(st, t)))
+                     else lambda x, t: dict(eval_fn(x, t)))
         st, ms = _scan_epochs(list(trace.epochs), plans, wfs, bounds,
                               runner, step_keys, state0, B=B, cmax=cmax,
                               e_a=e_a, H=H, p=p, p_pad=p_pad,

@@ -136,7 +136,7 @@ class LMProblem:
     cfg: Any                    # models.config.ModelConfig
     shard: LMShardConfig
     spec: RavelSpec
-    params0: Any                # init pytree (the x0 everyone broadcasts)
+    params0: Any                # host init pytree (the x0 everyone starts at)
     eval_tokens: jnp.ndarray    # (Be, S) held-out eval batch
     eval_labels: jnp.ndarray    # (Be, S)
 
@@ -227,7 +227,9 @@ def make_lm_problem(
     shard = LMShardConfig(vocab=cfg.vocab, batch_per_node=batch_per_node,
                           seq_len=seq_len, n_nodes=n_nodes, seed=seed,
                           zipf=zipf)
-    params0 = init_params(cfg, jax.random.PRNGKey(seed))
+    # kept on the host: the engines take x0 where they need it, and no
+    # device has to carry an idle copy of the initial weights
+    params0 = jax.device_get(init_params(cfg, jax.random.PRNGKey(seed)))
     spec = make_ravel_spec(params0, pad_to=pad_to)
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x0E7A1]))
     shape = (eval_batch, seq_len + 1)

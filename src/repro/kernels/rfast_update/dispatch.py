@@ -30,7 +30,9 @@ Execution modes (:func:`resolve_mode` maps the engines' tri-state
   interpreter's per-operand overhead.
 
 ``interpret=None`` (the default everywhere) resolves to ``compiled`` on
-TPU and ``emulate`` elsewhere.
+TPU and ``emulate`` elsewhere — visibly: ``launch/train.py`` prints the
+resolved mode, and ``chip_smoke.py`` fails unless every cached key is
+``compiled``.
 
 Under the mesh-mapped sweep engine the commit runs *inside* a shard_map
 region, so the shapes that reach :func:`lookup` are the **local shard
@@ -45,7 +47,7 @@ from typing import Callable
 
 import jax
 
-__all__ = ["MODES", "resolve_mode", "lookup", "stats", "clear"]
+__all__ = ["MODES", "resolve_mode", "lookup", "stats", "keys", "clear"]
 
 MODES = ("compiled", "interpret", "emulate")
 
@@ -87,6 +89,12 @@ def stats() -> dict:
     distinct launch signatures constructed since the last :func:`clear`;
     a steady-state engine loop must not grow them."""
     return {"hits": _hits, "misses": _misses, "entries": len(_cache)}
+
+
+def keys() -> list[tuple]:
+    """The cached launch signatures; each key's second entry is its
+    execution mode."""
+    return list(_cache)
 
 
 def clear() -> None:

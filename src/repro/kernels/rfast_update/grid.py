@@ -6,17 +6,20 @@ wavefront (or a whole fleet wave) multiplies that overhead by the lane
 count.  This module replaces the vmap with a single launch whose grid
 spans **(lane, p-tile)**: scalar-prefetched int32 slot tables drive the
 ``BlockSpec`` index maps, so each grid step gathers its lane's z/g/ρ/ρ̃
-block rows directly from the packed state arrays —
+block rows directly from the packed state arrays (each row the flat
+parameter axis, stored lane-dense as ``(p // LANE, LANE)`` by the
+engines) —
 
-* ``z_src``/``go_src`` — the flattened ``(S·n·4, p)`` node state (the
-  wavefront engines pass the same array twice; the protocol round passes
-  its separate z/g leaves),
-* ``ri_src``           — the ``(H·S·e_a, p)`` delta-history rows,
-* ``rb_src``/``ro_src`` — the ``(2·S·e_a, p)`` flat ρ/ρ̃ state
+* ``z_src``/``go_src`` — the ``S·n·4`` node-state rows (the wavefront
+  engines pass the same array twice; the protocol round passes its
+  separate z/g leaves),
+* ``ri_src``           — the ``H·S·e_a`` delta-history rows,
+* ``rb_src``/``ro_src`` — the ``2·S·e_a`` ρ/ρ̃ rows
 
 — instead of materializing ``(B, k, p)`` neighbour stacks host-side.
-Per-lane float parameters (a_self, mask, a_out) ride along as regular
-blocked operands (Mosaic scalar prefetch is int32-only).
+Per-lane float parameters (a_self, mask, a_out) ride along whole in
+SMEM (Mosaic scalar prefetch is int32-only, and a ``(1, k)`` VMEM block
+of a ``(B, k)`` table breaks the TPU's (8, 128) tiling rule).
 
 Three execution modes share this entry point (see
 :mod:`.dispatch`): ``compiled`` (the real TPU launch), ``interpret``
@@ -42,6 +45,7 @@ inertness contract of the jnp wavefront path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +73,9 @@ def block_pad_width(p: int, shards: int = 1) -> int:
 
 def _grid_kernel(ka: int, ko: int):
     """Kernel body for one (lane, p-tile) grid step.  The five prefetch
-    refs (consumed by the index maps) arrive first; per-lane floats and
-    the gathered (1, BLK_R, LANE) source blocks follow."""
+    refs (consumed by the index maps) arrive first; the whole per-lane
+    float tables (SMEM, read at this step's lane) and the gathered
+    (1, BLK_R, LANE) source blocks follow."""
 
     def kernel(*refs):
         (a_self_ref, mask_ref, a_out_ref,
@@ -80,29 +85,26 @@ def _grid_kernel(ka: int, ko: int):
         ro = rest[2 * ka:2 * ka + ko]
         z_o, ro_o, rb_o = rest[2 * ka + ko:]
 
+        b = pl.program_id(0)
         f32 = jnp.float32
         z = z_ref[0].astype(f32)
         recv = jnp.zeros_like(z)
         for k in range(ka):
-            m = mask_ref[0, k]
+            m = mask_ref[b, k]
             recv += m * (ri[k][0].astype(f32) - rb[k][0].astype(f32))
         z_half = z + recv + gn_ref[0].astype(f32) - go_ref[0].astype(f32)
 
-        z_o[0] = (a_self_ref[0, 0] * z_half).astype(z_o.dtype)
+        z_o[0] = (a_self_ref[b] * z_half).astype(z_o.dtype)
         for k in range(ko):
             ro_o[0, k] = (ro[k][0].astype(f32)
-                          + a_out_ref[0, k] * z_half).astype(ro_o.dtype)
+                          + a_out_ref[b, k] * z_half).astype(ro_o.dtype)
         for k in range(ka):
-            m = mask_ref[0, k]
+            m = mask_ref[b, k]
             rb_o[0, k] = (m * ri[k][0].astype(f32)
                           + (1.0 - m) * rb[k][0].astype(f32)
                           ).astype(rb_o.dtype)
 
     return kernel
-
-
-def _lane_map(b, t, iz, ig, iri, irb, iro):
-    return (b, 0)
 
 
 def _z_map(b, t, iz, ig, iri, irb, iro):
@@ -143,10 +145,9 @@ def _build_launch(B: int, T: int, ka: int, ko: int, dtypes: tuple,
     z_dt, ro_dt, rb_dt = dtypes
     R = T * BLK_R
     blk = lambda idx_fn: pl.BlockSpec((1, BLK_R, LANE), idx_fn)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((1, 1), _lane_map),      # a_self
-        pl.BlockSpec((1, ka), _lane_map),     # mask
-        pl.BlockSpec((1, ko), _lane_map),     # a_out
+        smem, smem, smem,                     # a_self (B,), mask, a_out
         blk(_z_map), blk(_gn_map), blk(_g_map),
     ]
     in_specs += [blk(functools.partial(_ri_map, k)) for k in range(ka)]
@@ -174,17 +175,17 @@ def _emulate(idx_z, idx_g, idx_ri, idx_rb, idx_ro, a_self, mask, a_out,
     masked blend — an XLA program per launch instead of a kernel, with
     bit-matching semantics (fp32 accumulation over the tiny k axis)."""
     f32 = jnp.float32
-    z = z_src[idx_z].astype(f32)                       # (B, Pf)
+    bc = lambda a: a.astype(f32).reshape(a.shape + (1,) * (g_new.ndim - 1))
+    z = z_src[idx_z].astype(f32)                       # (B, *row)
     go = go_src[idx_g].astype(f32)
-    ri = ri_src[idx_ri].astype(f32)                    # (B, ka, Pf)
+    ri = ri_src[idx_ri].astype(f32)                    # (B, ka, *row)
     rb = rb_src[idx_rb].astype(f32)
     ro = ro_src[idx_ro].astype(f32)
-    m = mask.astype(f32)[..., None]
+    m = bc(mask)
     recv = jnp.sum(m * (ri - rb), axis=1)
     z_half = z + recv + g_new.astype(f32) - go
-    z_o = (a_self.astype(f32)[:, None] * z_half).astype(z_src.dtype)
-    ro_o = (ro + a_out.astype(f32)[..., None]
-            * z_half[:, None]).astype(ro_src.dtype)
+    z_o = (bc(a_self) * z_half).astype(z_src.dtype)
+    ro_o = (ro + bc(a_out) * z_half[:, None]).astype(ro_src.dtype)
     rb_o = (m * ri + (1.0 - m) * rb).astype(rb_src.dtype)
     return z_o, ro_o, rb_o
 
@@ -201,16 +202,19 @@ def commit_grid(idx_z, idx_g, idx_ri, idx_rb, idx_ro,
       idx_rb: (B, ka) int32 rows of ``rb_src`` (receiver ρ̃ buffers).
       idx_ro: (B, ko) int32 rows of ``ro_src`` (sender ρ running sums).
       a_self: (B,); mask: (B, ka) 0/1; a_out: (B, ko) floats.
-      z_src/go_src/ri_src/rb_src/ro_src: (rows, Pf) flat sources —
+      z_src/go_src/ri_src/rb_src/ro_src: (rows, *row) sources, each row
+        either flat ``(Pf,)`` or lane-dense ``(Pf // LANE, LANE)`` —
         aliasing is fine (the engines pass one array several times).
-      g_new: (B, Pf) — this lane's fresh gradient, indexed by lane.
+        Lane-dense rows are the kernel's own block layout; flat rows
+        cost the TPU a relayout copy of every source.
+      g_new: (B, *row) — this lane's fresh gradient, indexed by lane.
       mode: dispatch mode (see :mod:`.dispatch`); None autodetects.
         ``compiled``/``interpret`` require ``Pf`` to be a multiple of
         ``BLK_R·LANE`` (pre-pad with :func:`block_pad_width` — the zero
         tail is inert under the linear commit); ``emulate`` takes any Pf.
 
-    Returns ``(z' (B, Pf), rho_out' (B, ko, Pf), rho_buf' (B, ka, Pf))``
-    in the respective source dtypes.  All index tables are clamped into
+    Returns ``(z' (B, *row), rho_out' (B, ko, *row), rho_buf' (B, ka,
+    *row))`` in the respective source dtypes.  All index tables are clamped into
     their source's row range (drop-sentinel lanes must be discarded by
     the caller's scatters).
     """
@@ -221,7 +225,8 @@ def commit_grid(idx_z, idx_g, idx_ri, idx_rb, idx_ro,
                          f"got {mode!r}")
     B, ka = idx_ri.shape
     ko = idx_ro.shape[1]
-    Pf = z_src.shape[-1]
+    row = g_new.shape[1:]
+    Pf = math.prod(row)
     i32 = lambda a, hi: jnp.clip(a.astype(jnp.int32), 0, hi - 1)
     idx_z = i32(idx_z, z_src.shape[0])
     idx_g = i32(idx_g, go_src.shape[0])
@@ -254,8 +259,8 @@ def commit_grid(idx_z, idx_g, idx_ri, idx_rb, idx_ro,
     f32 = jnp.float32
     z_o, ro_o, rb_o = launch(
         idx_z, idx_g, idx_ri, idx_rb, idx_ro,
-        a_self.astype(f32)[:, None], mask.astype(f32), a_out.astype(f32),
+        a_self.astype(f32), mask.astype(f32), a_out.astype(f32),
         b3(z_src), b3(g_new), b3(go_src),
         *([b3(ri_src)] * ka), *([b3(rb_src)] * ka), *([b3(ro_src)] * ko))
-    return (z_o.reshape(B, Pf), ro_o.reshape(B, ko, Pf),
-            rb_o.reshape(B, ka, Pf))
+    return (z_o.reshape((B,) + row), ro_o.reshape((B, ko) + row),
+            rb_o.reshape((B, ka) + row))

@@ -14,7 +14,7 @@ import argparse
 import json
 
 from repro.launch.dryrun import run_case
-from repro.launch.mesh import HW
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, peak_rates
 
 
 def terms(rec: dict) -> str:
@@ -29,9 +29,10 @@ def terms(rec: dict) -> str:
         co = sum(v["bytes"]
                  for v in rec.get("collectives_scanned", {}).values())
     mem = rec["memory"]
-    return (f"compute={fl/HW['peak_flops_bf16']:.3f}s "
-            f"memory={by/HW['hbm_bw']:.3f}s "
-            f"collective={co/HW['ici_bw']:.3f}s "
+    hw = peak_rates(DRYRUN_DEVICE_KIND)
+    return (f"compute={fl/hw['peak_flops_bf16']:.3f}s "
+            f"memory={by/hw['hbm_bw']:.3f}s "
+            f"collective={co/hw['ici_bw']:.3f}s "
             f"args={mem['argument_size_in_bytes']/2**30:.1f}GiB "
             f"temp={mem['temp_size_in_bytes']/2**30:.1f}GiB")
 

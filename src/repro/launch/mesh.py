@@ -11,13 +11,16 @@ import jax
 import numpy as np
 
 __all__ = ["make_production_mesh", "make_sweep_mesh", "node_axes_for",
-           "HW"]
+           "PEAKS", "DRYRUN_DEVICE_KIND", "peak_rates"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # GSPMD (Auto) axes: the specs place arrays by sharding rules, not
+    # by sharding-in-types
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_sweep_mesh(*, lanes: int | None = None, param_shards: int = 1,
@@ -72,9 +75,28 @@ def node_axes_for(mesh, *, n_nodes: int | None = None) -> tuple[str, ...]:
     raise ValueError(f"unsupported n_nodes={n_nodes} for mesh {names}")
 
 
-# TPU v5e hardware constants for the roofline (per chip)
-HW = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
+# Peak rates of one chip, keyed by jax's ``Device.device_kind``.
+# "TPU v5 lite" is the TPU v5e; source: Google Cloud documentation,
+# "TPU v5e" (system architecture): 197 TFLOP/s bf16, 819 GB/s of HBM
+# bandwidth, and 1,600 Gbit/s of chip-to-chip interconnect over four ICI
+# links, i.e. 50 GB/s per link.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+    },
 }
+
+# the chip the production dry-run meshes (make_production_mesh) model
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peak_rates(device_kind: str) -> dict:
+    """The :data:`PEAKS` entry of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak rates for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None

@@ -26,7 +26,7 @@ import json
 import os
 
 from repro.configs import get_config
-from repro.launch.mesh import HW
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, peak_rates
 from repro.launch.specs import SHAPES
 
 HBM_PER_CHIP = 16 * 2**30          # v5e
@@ -97,9 +97,10 @@ def analyze(path: str) -> dict | None:
     ssm_fix = ssm_correction_flops(cfg, rec["shape"], kind) / chips
     fl_pd_corr = fl_pd + ssm_fix
 
-    compute_s = fl_pd_corr / HW["peak_flops_bf16"]
-    memory_s = by_pd / HW["hbm_bw"]
-    coll_s = co_pd / HW["ici_bw"]
+    hw = peak_rates(DRYRUN_DEVICE_KIND)
+    compute_s = fl_pd_corr / hw["peak_flops_bf16"]
+    memory_s = by_pd / hw["hbm_bw"]
+    coll_s = co_pd / hw["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dom = max(terms, key=terms.get)
 

@@ -49,6 +49,8 @@ from repro.core.simulator import (run_epochs, run_rfast, run_sweep,
 from repro.core.topology import get_topology
 from repro.data.objectives import make_lm_problem
 from repro.data.pipeline import LMShardConfig, node_batch
+from repro.kernels.rfast_update import dispatch
+from repro.launch.xla_env import configure_compile_cache, force_host_devices
 from repro.models.transformer import init_params, loss_fn
 from repro.optim.schedules import warmup_cosine
 
@@ -103,8 +105,8 @@ def main(argv=None) -> dict:
                          "over every compiled plan before training "
                          "(raises PlanInvariantError on any diagnostic)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     if args.host_devices:
-        from repro.launch.xla_env import force_host_devices
         force_host_devices(args.host_devices)
 
     if args.list_scenarios:
@@ -241,6 +243,7 @@ def _train_sync(args, cfg) -> dict:
 # fully asynchronous (scenario trace through the wavefront engine)
 # --------------------------------------------------------------------- #
 def _train_async(args, cfg) -> dict:
+    t0 = time.perf_counter()
     n = args.nodes
     topo = get_topology(args.topology, n)
     prob = make_lm_problem(cfg, n, batch_per_node=args.batch_per_node,
@@ -260,12 +263,27 @@ def _train_async(args, cfg) -> dict:
     attempts = outdeg[:, sched.agent].sum()
     delivered = float((trace.send_ok_w.sum() + trace.send_ok_a.sum())
                       / max(1.0, attempts))
+    # the commit's execution mode, resolved where the engines resolve it:
+    # compiled on TPU, the jnp emulation twin elsewhere
+    mode = dispatch.resolve_mode(None) if args.impl == "pallas" else "-"
     print(f"arch={cfg.name} p={prob.p} ({prob.spec.p_model} model) "
           f"nodes={n} topo={topo.name} scenario={args.scenario} "
           f"K={K} D={sched.D} T={sched.T} "
-          f"send_ok={delivered:.2f} impl={args.impl}")
+          f"send_ok={delivered:.2f} impl={args.impl} mode={mode}")
 
+    mesh = replicated = None
     x0 = prob.x0_flat
+    if args.param_shards > 1:
+        # one lane, flat parameter axis sharded over `model`: the
+        # p >= 100M path (DESIGN.md §13).  x0 is replicated, so no
+        # device carries more of it than another.
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch.mesh import make_sweep_mesh
+        mesh = make_sweep_mesh(lanes=1, param_shards=args.param_shards)
+        replicated = NamedSharding(mesh, PartitionSpec())
+        x0 = jax.device_put(x0, replicated)
+        print(f"mesh: 1x{args.param_shards} (lane x param shards) over "
+              f"{len(jax.devices())} devices")
     # chunk (= eval/ckpt) boundaries: log_every activations per node
     eval_every = max(n, min(K, args.log_every * n))
     save_every_chunks = max(1, args.ckpt_every // max(1, args.log_every))
@@ -278,69 +296,54 @@ def _train_async(args, cfg) -> dict:
 
     logger = MetricsLogger(args.metrics) if args.metrics else None
     timer = StepTimer()
-    t0 = time.perf_counter()
     losses: list[float] = [float(prob.mean_loss(x0))]
     print(f"event {0:6d} loss {losses[0]:.4f} (init)", flush=True)
-
-    def eval_fn(state, t):
-        l = float(prob.mean_loss(state.x.mean(0)))
-        return {"loss": l, "t": t}
-
     published: list[int] = []
+    wall: list[float] = []           # seconds since start at each eval
+    k0 = int(state0.k) if state0 is not None else 0
 
-    def chunk_cb(state, k):
+    def eval_and_log(x, t):
+        # x: the (n, p) node iterates; the mean is reduced where x lives
+        # (sharded over the mesh on the --param-shards path), and the
+        # model then sees a replicated x̄: a sharded one makes XLA
+        # partition the whole forward pass, a compile of minutes
+        x_bar = x.mean(0)
+        if replicated is not None:
+            x_bar = jax.device_put(x_bar, replicated)
+        losses.append(float(prob.mean_loss(x_bar)))
+        ev = min(K, k0 + (len(losses) - 1) * eval_every)
         timer.tick()
+        wall.append(time.perf_counter() - t0)
+        print(f"event {ev:6d} loss {losses[-1]:.4f} "
+              f"vtime {t:8.1f} ({wall[-1]:.1f}s)", flush=True)
         if logger:
-            logger.log(k, loss=losses[-1], sps=timer.steps_per_sec)
-        if args.ckpt and (k >= K
-                          or (k // eval_every) % save_every_chunks == 0):
-            save_checkpoint(args.ckpt, k, state)
+            logger.log(ev, loss=losses[-1], sps=timer.steps_per_sec)
         if args.publish_dir:
             # serving checkpoint: the consensus average x̄ unraveled back
             # to the model pytree — what launch/serve.py hot-swaps in
-            save_checkpoint(args.publish_dir, k,
-                            unravel(prob.spec, state.x.mean(0)))
-            published.append(k)
+            save_checkpoint(args.publish_dir, ev,
+                            unravel(prob.spec, x_bar))
+            published.append(ev)
+        return {"loss": losses[-1], "t": t}
 
-    k0 = int(state0.k) if state0 is not None else 0
-    def eval_and_log(state, t):
-        m = eval_fn(state, t)
-        losses.append(m["loss"])
-        ev = min(K, k0 + (len(losses) - 1) * eval_every)
-        dt = time.perf_counter() - t0
-        print(f"event {ev:6d} loss {m['loss']:.4f} "
-              f"vtime {t:8.1f} ({dt:.1f}s)", flush=True)
-        return m
+    def chunk_cb(state, k):
+        if k >= K or (k // eval_every) % save_every_chunks == 0:
+            save_checkpoint(args.ckpt, k, state)
 
-    if args.param_shards > 1:
-        # one lane, flat parameter axis sharded over `model`: the
-        # p >= 100M path (DESIGN.md §13).  No chunk_cb/state0 hooks —
-        # --ckpt was rejected in main(); logging rides eval_and_log.
-        from repro.launch.mesh import make_sweep_mesh
-        mesh = make_sweep_mesh(lanes=1, param_shards=args.param_shards)
-        print(f"mesh: 1x{args.param_shards} (lane x param shards) over "
-              f"{len(jax.devices())} devices")
-
-        def eval_log_sharded(state, t):
-            m = eval_and_log(state, t)
-            timer.tick()
-            if logger:
-                logger.log(min(K, k0 + (len(losses) - 1) * eval_every),
-                           loss=m["loss"], sps=timer.steps_per_sec)
-            return m
-
+    if mesh is not None:
+        # no chunk_cb/state0 hooks: --ckpt was rejected in main()
         states, _ = run_sweep(
-            topo, [sched], prob, jnp.tile(x0[None], (n, 1)), args.gamma,
-            seeds=[args.seed], eval_every=eval_every,
-            eval_fn=eval_log_sharded, impl=args.impl,
+            topo, [sched], prob, x0, args.gamma, seeds=[args.seed],
+            eval_every=eval_every, eval_fn=eval_and_log, impl=args.impl,
             verify_plans=args.verify_plans, mesh=mesh)
         state = states[0]
     else:
         state, _ = run_rfast(
-            topo, sched, prob, jnp.tile(x0[None], (n, 1)), args.gamma,
-            seed=args.seed, eval_every=eval_every, eval_fn=eval_and_log,
-            mode="wavefront", impl=args.impl, state0=state0,
-            chunk_cb=chunk_cb, verify_plans=args.verify_plans)
+            topo, sched, prob, x0, args.gamma, seed=args.seed,
+            eval_every=eval_every, eval_fn=eval_and_log, mode="wavefront",
+            impl=args.impl, state0=state0,
+            chunk_cb=chunk_cb if args.ckpt else None,
+            verify_plans=args.verify_plans)
     if logger:
         logger.close()
     if len(losses) > 1:
@@ -350,7 +353,8 @@ def _train_async(args, cfg) -> dict:
         print("done (schedule already complete)")
     return {"mode": "async", "scenario": args.scenario,
             "losses": losses, "events": K, "published": published,
-            "vtime": float(sched.times[-1]), "send_ok": delivered}
+            "vtime": float(sched.times[-1]), "send_ok": delivered,
+            "commit_mode": mode, "eval_wall": wall, "state": state}
 
 
 # --------------------------------------------------------------------- #
@@ -384,8 +388,8 @@ def _train_async_dynamic(args, cfg, prob, topo, sc, K) -> dict:
 
     vt = {"t": 0.0}
 
-    def eval_and_log(state, t):
-        l = float(prob.mean_loss(state.x.mean(0)))
+    def eval_and_log(x, t):
+        l = float(prob.mean_loss(x.mean(0)))
         losses.append(l)
         vt["t"] = t
         return {"loss": l, "t": t}
@@ -407,7 +411,7 @@ def _train_async_dynamic(args, cfg, prob, topo, sc, K) -> dict:
             published.append(k)
 
     state, metrics = run_epochs(
-        et, prob, jnp.tile(x0[None], (n, 1)), args.gamma,
+        et, prob, x0, args.gamma,
         seed=args.seed, eval_every=eval_every, eval_fn=eval_and_log,
         impl=args.impl, chunk_cb=chunk_cb, verify_plans=args.verify_plans)
     if logger:

@@ -6,13 +6,15 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 
 import jax  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.launch.specs import build_case  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for arch in ("llama3-8b", "deepseek-v2-236b", "falcon-mamba-7b",
                  "whisper-large-v3"):
         cfg = get_config(arch).reduced()
@@ -34,8 +36,6 @@ def main():
             compiled = jax.jit(fn).lower(*args).compile()
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict/device
-                ca = ca[0]
             assert ma.argument_size_in_bytes > 0
             assert ca.get("flops", 0) > 0
             print(f"OK {arch} {shape} args="

@@ -225,10 +225,8 @@ def test_rf201_callback_in_scan():
 
 
 def test_rf202_f64_promotion():
-    from jax.experimental import enable_x64
-
     from repro.analysis import jaxlint
-    with enable_x64():
+    with jax.enable_x64(True):
         cj = jax.make_jaxpr(lambda x: x * np.float64(1.5))(np.float64(2.0))
     assert codes(jaxlint.audit_jaxpr(cj, subject="m")) == ["RF202"]
 
@@ -290,7 +288,11 @@ def test_rf206_state_sized_collective_in_mesh_body():
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis import jaxlint
-    from repro.core.runtime_sharded import _shard_map
+
+    def _shard_map(fn, mesh, in_specs, out_specs, axes):
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, axis_names=set(axes),
+                             check_vma=False)
 
     mesh = jax.sharding.Mesh(
         np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))

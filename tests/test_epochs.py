@@ -49,7 +49,7 @@ def test_single_epoch_matches_run_rfast_oracle(sc_name):
     et = sc.realize_epochs(topo, K, seed=3)
     assert len(et.epochs) == 1
     x0 = jnp.zeros((n, prob.p), jnp.float32)
-    ev = lambda s, t: {"m": float(jnp.sum(jnp.abs(s.x))), "t": t}
+    ev = lambda x, t: {"m": float(jnp.sum(jnp.abs(x))), "t": t}
     st_o, ms_o = run_rfast(topo, tr.schedule, prob, x0, 5e-3, seed=3,
                            eval_every=100, eval_fn=ev, mode="wavefront")
     st_e, ms_e = run_epochs(et, prob, x0, 5e-3, seed=3,
@@ -128,7 +128,7 @@ def test_root_failover_epochized_converges_frozen_stalls():
     topo = robust_tree(n)
     sc = get_scenario("root_failover", n)
     x0 = jnp.zeros((n, prob.p), jnp.float32)
-    ev = lambda s, t: {"loss": float(prob.mean_loss(jnp.mean(s.x, 0))),
+    ev = lambda x, t: {"loss": float(prob.mean_loss(jnp.mean(x, 0))),
                        "t": t}
     et = sc.realize_epochs(topo, K, seed=0)
     assert len(et.epochs) == 2 and et.epochs[1].root != 0
@@ -162,7 +162,7 @@ def test_sweep_epochs_lane_matches_solo_run():
                                   scenario=get_scenario("root_failover", n),
                                   seeds=seeds)
     x0 = jnp.zeros((n, prob.p), jnp.float32)
-    ev = lambda s, t: {"m": float(jnp.sum(jnp.abs(s.x))), "t": t}
+    ev = lambda x, t: {"m": float(jnp.sum(jnp.abs(x))), "t": t}
     sts, mss = run_sweep_epochs(traces, prob, x0, 5e-3, seeds=list(seeds),
                                 eval_every=300, eval_fn=ev)
     st0, ms0 = run_epochs(traces[0], prob, x0, 5e-3, seed=0,

@@ -24,7 +24,8 @@ from repro.core.plan import build_comm_plan, pad_comm_plan
 from repro.core.schedule import (build_wavefront_plan, pad_plan,
                                  stack_plans)
 from repro.core.simulator import (init_state, pack_state,
-                                  rfast_wavefront_scan, wave_inputs)
+                                  rfast_wavefront_scan, unpack_state,
+                                  wave_inputs)
 from tests.test_simulator import quad_grad_fn
 
 jax.config.update("jax_enable_x64", False)
@@ -63,7 +64,8 @@ def test_padded_waves_and_lanes_commit_zero_delta(seed, loss, impl):
     step_keys = jax.random.split(key, K)
     state0 = init_state(plan, jnp.zeros((n, p), jnp.float32), gfn,
                         init_key, H)
-    runner = rfast_wavefront_scan(plan, gfn, 0.02, donate=False, impl=impl)
+    runner = rfast_wavefront_scan(plan, gfn, 0.02, donate=False, impl=impl,
+                                  p_real=p)
 
     base = runner(pack_state(state0), wave_inputs(wf, step_keys))
 
@@ -199,8 +201,8 @@ def test_run_sweep_randomized_matrix():
     x0 = jnp.zeros((n, p), jnp.float32)
     ev = 150
 
-    def eval_fn(st, t):
-        return {"xm": float(jnp.mean(st.x)), "t": t}
+    def eval_fn(x, t):
+        return {"xm": float(jnp.mean(x)), "t": t}
 
     states, metrics = run_sweep([t for _, t, _ in lanes], scheds, gfn, x0,
                                 0.02, seeds=[s for _, _, s in lanes],
@@ -291,8 +293,8 @@ def test_run_sweep_pallas_single_dispatch_signature():
 def test_wavefront_pallas_block_padded_p_is_inert():
     """The compiled-mode contract on CPU: zero-padding the flat
     parameter axis to a block multiple (pack_state(p_pad=...) +
-    p_real=p threading) realizes the exact unpadded trajectory, and the
-    pad tail stays identically zero."""
+    p_real=p threading) realizes the exact trajectory of the default
+    (one LANE row) padding, and the pad tail stays identically zero."""
     from repro.kernels.rfast_update.grid import block_pad_width
 
     n, p, K = 5, 7, 150
@@ -309,14 +311,17 @@ def test_wavefront_pallas_block_padded_p_is_inert():
                         init_key, H)
     waves = wave_inputs(wf, step_keys)
 
-    base = rfast_wavefront_scan(plan, gfn, 0.02, donate=False,
-                                impl="pallas")(pack_state(state0), waves)
     # p_real must slice before grad_fn: quad_grad_fn rejects padded x
+    base = rfast_wavefront_scan(plan, gfn, 0.02, donate=False,
+                                impl="pallas",
+                                p_real=p)(pack_state(state0), waves)
     Pp = block_pad_width(p)
     padded = rfast_wavefront_scan(
         plan, gfn, 0.02, donate=False, impl="pallas",
         p_real=p)(pack_state(state0, p_pad=Pp), waves)
-    for name, a, b in zip(base._fields, base, padded):
+    base_st = unpack_state(base, 0, p=p)
+    whole = unpack_state(padded, 0)
+    for name, a, b in list(zip(base_st._fields, base_st, whole))[1:]:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b[..., :p]),
                                    rtol=1e-6, atol=1e-6, err_msg=name)
         assert not np.asarray(b[..., p:]).any(), name
