@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import metrics as tracing
 from ..kernels.rfast_update import dispatch
 from ..kernels.rfast_update.grid import block_pad_width, commit_grid
 from ..kernels.rfast_update.kernel import LANE
@@ -320,7 +321,8 @@ def _take(a: jnp.ndarray, index: tuple, p: int) -> jnp.ndarray:
     indexed rows are read.  ``index`` holds ints and ``(start, stop)``
     pairs (static, so one compile per distinct slice)."""
     idx = tuple(slice(*i) if isinstance(i, tuple) else i for i in index)
-    return _from_lanes(a[idx], p)
+    with jax.named_scope("rfast.iterates"):
+        return _from_lanes(a[idx], p)
 
 
 def pack_state(state: RFASTState, *, e_a: int | None = None,
@@ -549,34 +551,61 @@ def _wave_step(
     """
     # per-lane scalars broadcast over the trailing (R, LANE) axes
     bc = lambda a: a[..., None, None]
-    node_rows = state.nodes[w.agent]                       # (B, 4, R, L)
-    x_l, z_l, gp_l = node_rows[:, 0], node_rows[:, 2], node_rows[:, 3]
+    # named scopes tag every op of a phase in the device trace: rfast.mix,
+    # rfast.grad, rfast.commit, rfast.state_write
+    with jax.named_scope("rfast.mix"):
+        node_rows = state.nodes[w.agent]                   # (B, 4, R, L)
+        x_l, z_l, gp_l = node_rows[:, 0], node_rows[:, 2], node_rows[:, 3]
 
-    # (S.1) local descent -------------------------------------------------
-    v_new = descent_step(x_l, z_l, gamma)                  # (B, R, L)
+        # (S.1) local descent ---------------------------------------------
+        v_new = descent_step(x_l, z_l, gamma)              # (B, R, L)
 
-    # (S.2a) consensus pull, reads resolved to delta-history rows ----------
-    vals_v = state.v_hist[w.rslot_v, w.src_v]              # (B, kw, R, L)
-    # order the v_hist write below after this read: nothing else does,
-    # and XLA would otherwise keep a whole copy of the ring to serve it
-    vals_v, v_hist = jax.lax.optimization_barrier((vals_v, state.v_hist))
-    x_a = consensus_mix(bc(w.w_self), v_new, bc(w.w_in.swapaxes(0, 1)),
-                        vals_v.swapaxes(0, 1))             # sum over kw
+        # (S.2a) consensus pull, reads resolved to delta-history rows ------
+        vals_v = state.v_hist[w.rslot_v, w.src_v]          # (B, kw, R, L)
+        # order the v_hist write below after this read: nothing else
+        # does, and XLA would otherwise keep a whole copy of the ring to
+        # serve it
+        vals_v, v_hist = jax.lax.optimization_barrier((vals_v,
+                                                       state.v_hist))
+        x_a = consensus_mix(bc(w.w_self), v_new, bc(w.w_in.swapaxes(0, 1)),
+                            vals_v.swapaxes(0, 1))         # sum over kw
 
     # (S.2b) robust gradient tracking -------------------------------------
     # flat for the gradient and back; both sides of each relayout are
     # materialized (see _init_programs: fused, it compiles for minutes)
-    B = x_a.shape[0]
-    x_flat = jax.lax.optimization_barrier(x_a.reshape(B, -1))
-    p = x_flat.shape[-1]
-    if p_real is not None and p_real != p:
-        g_new = jax.vmap(grad_fn)(w.agent, x_flat[:, :p_real], w.keys)
-        g_new = jnp.pad(g_new, ((0, 0), (0, p - p_real)))
-    else:
-        g_new = jax.vmap(grad_fn)(w.agent, x_flat, w.keys)
-    g_new = jax.lax.optimization_barrier(
-        jax.lax.optimization_barrier(g_new).reshape(x_a.shape))
+    with jax.named_scope("rfast.grad"):
+        B = x_a.shape[0]
+        x_flat = jax.lax.optimization_barrier(x_a.reshape(B, -1))
+        p = x_flat.shape[-1]
+        if p_real is not None and p_real != p:
+            g_new = jax.vmap(grad_fn)(w.agent, x_flat[:, :p_real], w.keys)
+            g_new = jnp.pad(g_new, ((0, 0), (0, p - p_real)))
+        else:
+            g_new = jax.vmap(grad_fn)(w.agent, x_flat, w.keys)
+        g_new = jax.lax.optimization_barrier(
+            jax.lax.optimization_barrier(g_new).reshape(x_a.shape))
 
+    with jax.named_scope("rfast.commit"):
+        z_a, rho_new, rho_commit = _commit(state, w, z_l, gp_l, g_new,
+                                           ko=ko, impl=impl, mode=mode)
+
+    # commit: disjoint row scatters; (S.4) ρ̃ rows take the consumed values
+    with jax.named_scope("rfast.state_write"):
+        node_new = jnp.stack([x_a, v_new, z_a, g_new], axis=1)
+        return PackedState(
+            nodes=state.nodes.at[w.agent].set(node_new, mode="drop"),
+            rho2=state.rho2.at[w.rho_gidx].set(rho_commit, mode="drop"),
+            v_hist=v_hist.at[w.wslot, w.agent].set(v_new, mode="drop"),
+            rho_hist=state.rho_hist.at[w.wslot[:, None], w.rho_gidx[:, :ko]]
+            .set(rho_new, mode="drop"),
+        ), None
+
+
+def _commit(state: PackedState, w: _WaveInputs, z_l, gp_l, g_new, *,
+            ko: int, impl: str, mode: str):
+    """S.2b/c + S.4 of one wave: ``(z_a, rho_new, rho_commit)``, the new
+    z rows, the out-edge ρ rows and every ρ/ρ̃ row the wave writes."""
+    bc = lambda a: a[..., None, None]
     if impl == "pallas" and mode != "interpret":
         # one fused launch for the whole wave: gather tables over the
         # state rows.  The kernel's masked ρ̃ blend equals the jnp
@@ -609,7 +638,7 @@ def _wave_step(
             flat(z_l), flat(g_new), flat(gp_l), flat(vals_rho),
             flat(rho_rows[:, ko:]), w.a_val, flat(rho_rows[:, :ko]),
             w.out_wt, w.a_self)
-        lanes = lambda a: a.reshape(a.shape[:-1] + x_a.shape[-2:])
+        lanes = lambda a: a.reshape(a.shape[:-1] + z_l.shape[-2:])
         z_a, rho_new, buf_new = lanes(z_a), lanes(rho_new), lanes(buf_new)
         rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
     else:
@@ -623,16 +652,7 @@ def _wave_step(
         rho_new = rho_rows[:, :ko] \
             + bc(w.out_wt) * z_half[:, None]              # (B, ko, R, L)
         rho_commit = jnp.concatenate([rho_new, vals_rho], axis=1)
-
-    # commit: disjoint row scatters; (S.4) ρ̃ rows take the consumed values
-    node_new = jnp.stack([x_a, v_new, z_a, g_new], axis=1)
-    return PackedState(
-        nodes=state.nodes.at[w.agent].set(node_new, mode="drop"),
-        rho2=state.rho2.at[w.rho_gidx].set(rho_commit, mode="drop"),
-        v_hist=v_hist.at[w.wslot, w.agent].set(v_new, mode="drop"),
-        rho_hist=state.rho_hist.at[w.wslot[:, None], w.rho_gidx[:, :ko]]
-        .set(rho_new, mode="drop"),
-    ), None
+    return z_a, rho_new, rho_commit
 
 
 def rfast_wavefront_scan(
@@ -982,24 +1002,30 @@ def run_rfast(
                                   dispatch.resolve_mode(interpret)
                                   == "compiled"))
 
-    wf = build_wavefront_plan(schedule, plan, H, break_every=eval_every)
-    if verify_plans:
-        from ..analysis import planlint
-        planlint.check_or_raise(
-            planlint.lint_comm_plan(
-                plan, topo if isinstance(topo, Topology) else None)
-            + planlint.lint_wavefront_plan(wf, comm=plan,
-                                           schedule=schedule, H=H),
-            "run_rfast(verify_plans)")
-    runner = rfast_wavefront_scan(
-        plan, grad_fn, gamma, donate=True, impl=impl, interpret=interpret,
-        p_real=(p if p_pad != p else None))
-    waves = wave_inputs(wf, step_keys)
-    if state0 is None:
-        packed = _init_packed(grad_fn, x0, init_key[None], S=1, n=plan.n,
-                              H=H, e_a=max(1, plan.n_edges_a), p_pad=p_pad)
-    else:
-        packed = pack_state(state0, p_pad=p_pad)
+    with tracing.span("rfast.plan"):
+        wf = build_wavefront_plan(schedule, plan, H, break_every=eval_every)
+        if verify_plans:
+            from ..analysis import planlint
+            planlint.check_or_raise(
+                planlint.lint_comm_plan(
+                    plan, topo if isinstance(topo, Topology) else None)
+                + planlint.lint_wavefront_plan(wf, comm=plan,
+                                               schedule=schedule, H=H),
+                "run_rfast(verify_plans)")
+        runner = rfast_wavefront_scan(
+            plan, grad_fn, gamma, donate=True, impl=impl,
+            interpret=interpret, p_real=(p if p_pad != p else None))
+        waves = wave_inputs(wf, step_keys)
+    with tracing.span("rfast.init_state"):
+        if state0 is None:
+            packed = _init_packed(grad_fn, x0, init_key[None], S=1,
+                                  n=plan.n, H=H, e_a=max(1, plan.n_edges_a),
+                                  p_pad=p_pad)
+        else:
+            packed = pack_state(state0, p_pad=p_pad)
+        if tracing.enabled():
+            # time the init programs, not their enqueue (traced runs only)
+            jax.block_until_ready(packed)
 
     # chunk boundaries in wave space (waves never cross eval boundaries);
     # pad every chunk to the max wave count so the runner compiles once
@@ -1020,23 +1046,33 @@ def run_rfast(
                 [arr[w0:w1], jnp.full((pad,) + arr.shape[1:], fill,
                                       arr.dtype)])
 
-        chunk_waves = _WaveInputs(
-            agent=sl(waves.agent, n_pad), wslot=sl(waves.wslot, 0),
-            w_self=sl(waves.w_self, 0.0), a_self=sl(waves.a_self, 0.0),
-            rslot_v=sl(waves.rslot_v, 0), src_v=sl(waves.src_v, 0),
-            w_in=sl(waves.w_in, 0.0), rslot_rho=sl(waves.rslot_rho, 0),
-            hist_epos=sl(waves.hist_epos, 0), a_val=sl(waves.a_val, 0.0),
-            rho_gidx=sl(waves.rho_gidx, 2 * wf.e_a),
-            out_wt=sl(waves.out_wt, 0.0), keys=sl(waves.keys, 0))
-        packed = runner(packed, chunk_waves)
+        with tracing.span("rfast.chunk_inputs", ci):
+            chunk_waves = _WaveInputs(
+                agent=sl(waves.agent, n_pad), wslot=sl(waves.wslot, 0),
+                w_self=sl(waves.w_self, 0.0), a_self=sl(waves.a_self, 0.0),
+                rslot_v=sl(waves.rslot_v, 0), src_v=sl(waves.src_v, 0),
+                w_in=sl(waves.w_in, 0.0), rslot_rho=sl(waves.rslot_rho, 0),
+                hist_epos=sl(waves.hist_epos, 0),
+                a_val=sl(waves.a_val, 0.0),
+                rho_gidx=sl(waves.rho_gidx, 2 * wf.e_a),
+                out_wt=sl(waves.out_wt, 0.0), keys=sl(waves.keys, 0))
+        with tracing.span("rfast.run_waves", ci):
+            packed = runner(packed, chunk_waves)
         e = min(K, (ci + 1) * eval_every)
+        tracing.count("rfast.events", e - ci * eval_every, ci)
         if eval_fn is not None:
-            m = eval_fn(_iterates(packed, 0, n=plan.n, p=p),
-                        float(schedule.times[e - 1]))
+            with tracing.span("rfast.read_iterates", ci):
+                x = _iterates(packed, 0, n=plan.n, p=p)
+            with tracing.span("rfast.eval_fn", ci):
+                m = eval_fn(x, float(schedule.times[e - 1]))
+            # hold x no longer than eval_fn does: the next chunk's waves
+            # and eval read need its device memory
+            del x
             m["k"] = e
             metrics.append(m)
         if chunk_cb is not None:
-            chunk_cb(unpack_state(packed, e, p=p), e)
+            with tracing.span("rfast.chunk_cb", ci):
+                chunk_cb(unpack_state(packed, e, p=p), e)
     return _lane_states(packed, K, S=1, n=plan.n,
                         e_a=packed.rho_hist.shape[1], p=p,
                         consume=True)[0], metrics
@@ -1180,89 +1216,99 @@ def run_sweep(
         init_keys.append(init_key)
     step_keys = jnp.stack(lane_keys)                        # (S_pad, K, 2)
 
-    # per-lane plans, then chunk-aligned fleet stacking: chunk c of every
-    # lane is padded to the fleet-wide max chunk wave count, so chunk c
-    # occupies waves [c*cmax, (c+1)*cmax) in EVERY lane and one compiled
-    # scan body serves all chunks of all lanes
-    wfs = [build_wavefront_plan(schedules[s], padded_plans[s], H,
-                                break_every=eval_every, e_a=e_a)
-           for s in range(S_pad)]
-    chunk_starts = list(range(0, K, eval_every))
-    bounds = [[int(np.searchsorted(wf.event_start, c0))
-               for c0 in chunk_starts] + [wf.n_waves] for wf in wfs]
-    cmax = max(b[c + 1] - b[c]
-               for b in bounds for c in range(len(chunk_starts)))
-    B = max(wf.width for wf in wfs)
-    rechunked = []
-    for wf, b in zip(wfs, bounds):
-        rechunked.append(concat_plans(
-            [pad_plan(slice_plan(wf, b[c], b[c + 1]),
-                      width=B, n_waves=cmax, e_a=e_a)
-             for c in range(len(chunk_starts))]))
-    if verify_plans:
-        from ..analysis import planlint
-        diags = []
-        for s in range(S_pad):
-            diags += planlint.lint_comm_plan(
-                padded_plans[s], subject=f"lane{s}/comm")
-            diags += planlint.lint_wavefront_plan(
-                rechunked[s], comm=padded_plans[s],
-                schedule=schedules[s], H=H, subject=f"lane{s}")
-    if mesh is None:
-        stacked = stack_plans(rechunked)
-        fleet = flatten_plans(stacked)
+    with tracing.span("rfast.plan"):
+        # per-lane plans, then chunk-aligned fleet stacking: chunk c of every
+        # lane is padded to the fleet-wide max chunk wave count, so chunk c
+        # occupies waves [c*cmax, (c+1)*cmax) in EVERY lane and one compiled
+        # scan body serves all chunks of all lanes
+        wfs = [build_wavefront_plan(schedules[s], padded_plans[s], H,
+                                    break_every=eval_every, e_a=e_a)
+               for s in range(S_pad)]
+        chunk_starts = list(range(0, K, eval_every))
+        bounds = [[int(np.searchsorted(wf.event_start, c0))
+                   for c0 in chunk_starts] + [wf.n_waves] for wf in wfs]
+        cmax = max(b[c + 1] - b[c]
+                   for b in bounds for c in range(len(chunk_starts)))
+        B = max(wf.width for wf in wfs)
+        rechunked = []
+        for wf, b in zip(wfs, bounds):
+            rechunked.append(concat_plans(
+                [pad_plan(slice_plan(wf, b[c], b[c + 1]),
+                          width=B, n_waves=cmax, e_a=e_a)
+                 for c in range(len(chunk_starts))]))
         if verify_plans:
-            diags += planlint.lint_flatten(stacked, fleet, subject="fleet")
-        waves = wave_inputs(fleet, step_keys.reshape(S_pad * K, 2))
-        runner = rfast_sweep_scan(
-            grad_fn, gamma, ko=ko, n_per_lane=n, donate=True, impl=impl,
-            interpret=interpret, p_real=(p if p_pad != p else None))
-    else:
-        # one flattened program PER lane group, stacked on the leading
-        # device axis: every group shares the (cmax, S_loc·B) wave shape,
-        # so the shard_map body compiles once for all groups
-        group_waves = []
-        for g in range(D):
-            stacked = stack_plans(rechunked[g * S_loc:(g + 1) * S_loc])
+            from ..analysis import planlint
+            diags = []
+            for s in range(S_pad):
+                diags += planlint.lint_comm_plan(
+                    padded_plans[s], subject=f"lane{s}/comm")
+                diags += planlint.lint_wavefront_plan(
+                    rechunked[s], comm=padded_plans[s],
+                    schedule=schedules[s], H=H, subject=f"lane{s}")
+        if mesh is None:
+            stacked = stack_plans(rechunked)
             fleet = flatten_plans(stacked)
             if verify_plans:
-                diags += planlint.lint_flatten(stacked, fleet,
-                                               subject=f"fleet/g{g}")
-            group_waves.append(wave_inputs(
-                fleet,
-                step_keys[g * S_loc:(g + 1) * S_loc].reshape(S_loc * K,
-                                                             2)))
-        waves = jax.tree.map(lambda *a: jnp.stack(a), *group_waves)
-        runner = _mesh_sweep_scan(
-            grad_fn, gamma, ko=ko, n_per_lane=n, mesh=mesh,
-            lane_axis=lane_axis, param_axis=param_axis, donate=True,
-            impl=impl, interpret=interpret,
-            p_real=(p if p_pad != p else None))
-        st_sh, wv_sh = sweep_mesh_shardings(mesh, lane_axis, param_axis)
-        waves = jax.device_put(waves, jax.tree.map(wv_sh, waves))
-    if verify_plans:
-        planlint.check_or_raise(diags, "run_sweep(verify_plans)")
+                diags += planlint.lint_flatten(stacked, fleet, subject="fleet")
+            waves = wave_inputs(fleet, step_keys.reshape(S_pad * K, 2))
+            runner = rfast_sweep_scan(
+                grad_fn, gamma, ko=ko, n_per_lane=n, donate=True, impl=impl,
+                interpret=interpret, p_real=(p if p_pad != p else None))
+        else:
+            # one flattened program PER lane group, stacked on the leading
+            # device axis: every group shares the (cmax, S_loc·B) wave shape,
+            # so the shard_map body compiles once for all groups
+            group_waves = []
+            for g in range(D):
+                stacked = stack_plans(rechunked[g * S_loc:(g + 1) * S_loc])
+                fleet = flatten_plans(stacked)
+                if verify_plans:
+                    diags += planlint.lint_flatten(stacked, fleet,
+                                                   subject=f"fleet/g{g}")
+                group_waves.append(wave_inputs(
+                    fleet,
+                    step_keys[g * S_loc:(g + 1) * S_loc].reshape(S_loc * K,
+                                                                 2)))
+            waves = jax.tree.map(lambda *a: jnp.stack(a), *group_waves)
+            runner = _mesh_sweep_scan(
+                grad_fn, gamma, ko=ko, n_per_lane=n, mesh=mesh,
+                lane_axis=lane_axis, param_axis=param_axis, donate=True,
+                impl=impl, interpret=interpret,
+                p_real=(p if p_pad != p else None))
+            st_sh, wv_sh = sweep_mesh_shardings(mesh, lane_axis, param_axis)
+            waves = jax.device_put(waves, jax.tree.map(wv_sh, waves))
+        if verify_plans:
+            planlint.check_or_raise(diags, "run_sweep(verify_plans)")
 
     # the fleet init straight into the engine's layout (and, on a mesh,
     # its sharding): lane s's g0 comes from the lane's init key exactly
     # as in run_rfast, so the trajectories match the per-lane runs
     group_size = None if mesh is None else S_loc
-    packed = _init_packed(grad_fn, x0, jnp.stack(init_keys), S=S_pad,
-                          n=n, H=H, e_a=e_a, p_pad=p_pad,
-                          groups=None if mesh is None else D,
-                          sharding_of=None if mesh is None else st_sh)
+    with tracing.span("rfast.init_state"):
+        packed = _init_packed(grad_fn, x0, jnp.stack(init_keys), S=S_pad,
+                              n=n, H=H, e_a=e_a, p_pad=p_pad,
+                              groups=None if mesh is None else D,
+                              sharding_of=None if mesh is None else st_sh)
+        if tracing.enabled():
+            jax.block_until_ready(packed)
 
     metrics: list[list[dict]] = [[] for _ in range(S)]
     for ci in range(len(chunk_starts)):
         sl = (lambda a: a[:, ci * cmax:(ci + 1) * cmax]) if mesh is not \
             None else (lambda a: a[ci * cmax:(ci + 1) * cmax])
-        packed = runner(packed, jax.tree.map(sl, waves))
+        with tracing.span("rfast.chunk_inputs", ci):
+            chunk_waves = jax.tree.map(sl, waves)
+        with tracing.span("rfast.run_waves", ci):
+            packed = runner(packed, chunk_waves)
         e = min(K, (ci + 1) * eval_every)
+        tracing.count("rfast.events", S * (e - chunk_starts[ci]), ci)
         if eval_fn is not None:
             for s in range(S):
-                m = eval_fn(_iterates(packed, s, n=n, p=p,
-                                      group_size=group_size),
-                            float(schedules[s].times[e - 1]))
+                with tracing.span("rfast.read_iterates", ci):
+                    x = _iterates(packed, s, n=n, p=p, group_size=group_size)
+                with tracing.span("rfast.eval_fn", ci):
+                    m = eval_fn(x, float(schedules[s].times[e - 1]))
+                del x                    # as in run_rfast
                 m["k"] = e
                 metrics[s].append(m)
     states = _lane_states(
@@ -1394,6 +1440,7 @@ def _scan_epochs(epochs, plans, wfs, bounds, runner, step_keys, state0,
                              waves)
             packed = runner(packed, w)
             e_loc = min(ep.K, (ci + 1) * eval_every)
+            tracing.count("rfast.events", e_loc - ci * eval_every)
             kg = ep.k0 + e_loc
             if eval_fn is not None:
                 m = eval_fn(_iterates(packed, 0, n=packed.nodes.shape[0],

@@ -192,6 +192,7 @@ class LMProblem:
         cfg, spec = self.cfg, self.spec
 
         @jax.jit
+        @jax.named_scope("rfast.eval")
         def ev(x_flat, toks, labels):
             params = unravel(spec, x_flat)
             logits, aux = forward(cfg, params, toks)

@@ -166,7 +166,7 @@ def _build_launch(B: int, T: int, ka: int, ko: int, dtypes: tuple,
         out_shape=(jax.ShapeDtypeStruct((B, R, LANE), z_dt),
                    jax.ShapeDtypeStruct((B, ko, R, LANE), ro_dt),
                    jax.ShapeDtypeStruct((B, ka, R, LANE), rb_dt)),
-        interpret=interpret)
+        interpret=interpret, name="rfast_commit_grid")
 
 
 def _emulate(idx_z, idx_g, idx_ri, idx_rb, idx_ro, a_self, mask, a_out,

@@ -31,6 +31,8 @@ the same protocol (core/protocol.py) over the same CommPlan.
 from __future__ import annotations
 
 import argparse
+import json
+import os
 import time
 
 import jax
@@ -39,6 +41,7 @@ import numpy as np
 
 from repro.checkpoint import load_checkpoint, latest_step, save_checkpoint
 from repro.core.paramvec import unravel
+from repro import metrics as tracing
 from repro.metrics import MetricsLogger, StepTimer
 from repro.configs import ARCHS, get_config
 from repro.core.protocol import IMPLS
@@ -96,7 +99,10 @@ def main(argv=None) -> dict:
                          "boundary through checkpoint/ckpt.py's atomic "
                          "npz+manifest protocol — the feed that "
                          "launch/serve.py polls and hot-swaps from")
-    ap.add_argument("--metrics", default="", help="JSONL metrics path")
+    ap.add_argument("--metrics", default="",
+                    help="JSONL metrics path; with --scenario the "
+                         "engine's spans and counters also go to "
+                         "<path stem>.trace.jsonl")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
@@ -152,13 +158,46 @@ def main(argv=None) -> dict:
             if get_scenario(args.scenario, args.nodes).dynamic:
                 ap.error("--param-shards is not supported for dynamic "
                          "(membership) scenarios yet")
-        return _train_async(args, cfg)
+        if not args.metrics:
+            return _train_async(args, cfg)
+        # the engine's spans and counters, written beside the metrics
+        tracing.enable(True)
+        try:
+            return _train_async(args, cfg)
+        finally:
+            _write_record(args.metrics)
+            tracing.enable(False)
+            tracing.reset()
     if args.param_shards > 1:
         ap.error("--param-shards shards the wavefront engine's flat "
                  "parameter axis; the synchronous rounds already shard "
                  "the model pytree via GSPMD (pass --scenario for the "
                  "async regime)")
     return _train_sync(args, cfg)
+
+
+def _write_record(path: str) -> None:
+    """The program's spans and counts, one JSON object per line, in
+    ``<path stem>.trace.jsonl``."""
+    rec = tracing.record()
+    with open(os.path.splitext(path)[0] + ".trace.jsonl", "w") as f:
+        for kind in ("spans", "counts"):
+            for r in rec[kind]:
+                f.write(json.dumps(dict(kind=kind[:-1], **r)) + "\n")
+
+
+class _EventRate:
+    """Events per second between consecutive calls, from the engine's
+    ``rfast.events`` counter (tracing is on whenever a logger is)."""
+
+    def __init__(self):
+        self.events, self.t = 0, time.perf_counter()
+
+    def __call__(self) -> float:
+        events, t = tracing.total("rfast.events"), time.perf_counter()
+        rate = (events - self.events) / max(t - self.t, 1e-9)
+        self.events, self.t = events, t
+        return rate
 
 
 # --------------------------------------------------------------------- #
@@ -295,9 +334,9 @@ def _train_async(args, cfg) -> dict:
         print(f"resumed from event {int(state0.k)}/{K}")
 
     logger = MetricsLogger(args.metrics) if args.metrics else None
-    timer = StepTimer()
     losses: list[float] = [float(prob.mean_loss(x0))]
     print(f"event {0:6d} loss {losses[0]:.4f} (init)", flush=True)
+    rate = _EventRate()
     published: list[int] = []
     wall: list[float] = []           # seconds since start at each eval
     k0 = int(state0.k) if state0 is not None else 0
@@ -312,12 +351,11 @@ def _train_async(args, cfg) -> dict:
             x_bar = jax.device_put(x_bar, replicated)
         losses.append(float(prob.mean_loss(x_bar)))
         ev = min(K, k0 + (len(losses) - 1) * eval_every)
-        timer.tick()
         wall.append(time.perf_counter() - t0)
         print(f"event {ev:6d} loss {losses[-1]:.4f} "
               f"vtime {t:8.1f} ({wall[-1]:.1f}s)", flush=True)
         if logger:
-            logger.log(ev, loss=losses[-1], sps=timer.steps_per_sec)
+            logger.log(ev, loss=losses[-1], events_per_s=rate())
         if args.publish_dir:
             # serving checkpoint: the consensus average x̄ unraveled back
             # to the model pytree — what launch/serve.py hot-swaps in
@@ -381,10 +419,10 @@ def _train_async_dynamic(args, cfg, prob, topo, sc, K) -> dict:
     x0 = prob.x0_flat
     eval_every = max(n, min(K, args.log_every * n))
     logger = MetricsLogger(args.metrics) if args.metrics else None
-    timer = StepTimer()
     t0 = time.perf_counter()
     losses: list[float] = [float(prob.mean_loss(x0))]
     print(f"event {0:6d} loss {losses[0]:.4f} (init)", flush=True)
+    rate = _EventRate()
 
     vt = {"t": 0.0}
 
@@ -399,12 +437,11 @@ def _train_async_dynamic(args, cfg, prob, topo, sc, K) -> dict:
     published: list[int] = []
 
     def chunk_cb(state, k):
-        timer.tick()
         dt = time.perf_counter() - t0
         print(f"event {k:6d} loss {losses[-1]:.4f} vtime {vt['t']:8.1f} "
               f"({dt:.1f}s)", flush=True)
         if logger:
-            logger.log(k, loss=losses[-1], sps=timer.steps_per_sec)
+            logger.log(k, loss=losses[-1], events_per_s=rate())
         if args.publish_dir:
             save_checkpoint(args.publish_dir, k,
                             unravel(prob.spec, state.x.mean(0)))
