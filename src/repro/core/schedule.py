@@ -155,10 +155,10 @@ class WavefrontPlan:
     body touches no plan-indexed gathers — only the four state arrays.
     ρ and ρ̃ live in one ``(2·e_a, p)`` array on the device (ρ̃ rows at
     offset ``e_a``); ``rho_gidx``/``rho_tgt`` index that flat layout, and
-    invalid/padded entries carry the sentinel ``2·e_a`` which drop-mode
-    scatters discard (``e_a`` defaults to the plan's real A-edge count
-    but may be padded up for fleet stacking).  Lane padding uses sentinel
-    agent ``n`` (reads clamp, commits drop); ``kidx`` maps lanes to event
+    invalid/padded entries carry the sentinel ``2·e_a``, whose writes the
+    engines skip (``e_a`` defaults to the plan's real A-edge count but
+    may be padded up for fleet stacking).  Lane padding uses sentinel
+    agent ``n`` (reads clamp, writes are skipped); ``kidx`` maps lanes to event
     indices (sentinel ``K``) for per-event RNG keys.
 
     Every per-wave array is *fixed-shape and stackable*: :func:`pad_plan`
@@ -233,8 +233,8 @@ def pad_plan(wf: WavefrontPlan, *, width: int | None = None,
     stack into one fleet program.
 
     * ``width`` — append padded lanes to every wave.  A padded lane
-      carries sentinel agent ``n`` (node-row scatters drop), sentinel ρ
-      rows ``2·e_a`` (flat-ρ and ρ-history scatters drop), zero weights
+      carries sentinel agent ``n`` (no node-row write), sentinel ρ
+      rows ``2·e_a`` (no flat-ρ or ρ-history write), zero weights
       and validity (its reads contribute nothing anywhere), and kidx
       ``K`` (the zero RNG key row) — the same inertness argument as the
       engine's own chunk padding and the RavelSpec pad tail.
@@ -378,8 +378,8 @@ def grid_gather_tables(agent, rslot_rho, hist_epos, rho_gidx, *,
 
     ``e_a_flat`` is the flat ρ half-size E (``S·e_a`` after
     :func:`flatten_plans`).  Sentinel entries pass through untranslated
-    (``commit_grid`` clamps reads; commits drop at the caller's
-    scatters).  Works on numpy arrays and jax tracers alike.
+    (``commit_grid`` clamps reads; the caller skips their writes).
+    Works on numpy arrays and jax tracers alike.
     """
     agent = agent.astype("int32") * 4
     return (agent + 2, agent + 3,
@@ -485,7 +485,7 @@ def build_wavefront_plan(schedule: Schedule, plan, H: int, *,
     rslot_rho = slots_r[ev[:, None], ia_e]            # (K, ka)
 
     # flat ρ/ρ̃ indices: ρ rows at [0, e_a), ρ̃ rows at [e_a, 2·e_a);
-    # sentinel 2·e_a marks pad slots (drop-mode scatters discard them)
+    # sentinel 2·e_a marks pad slots (the engines skip their writes)
     if e_a is None:
         e_a = max(1, plan.n_edges_a)
     elif e_a < max(1, plan.n_edges_a):

@@ -64,6 +64,7 @@ from ..kernels.rfast_update.grid import block_pad_width, commit_grid
 from ..kernels.rfast_update.kernel import LANE
 
 SUBLANES = 8        # rows of one fp32 TPU tile: (SUBLANES, LANE)
+_UNROLL_LANES = 8   # wider waves write their rows in a fori_loop
 from ..kernels.rfast_update.ops import rfast_commit
 from .paramvec import GradProvider, as_grad_fn
 from .plan import CommPlan, as_comm_plan, pad_comm_plan
@@ -267,7 +268,8 @@ def rfast_scan(
 # --------------------------------------------------------------------- #
 class PackedState(NamedTuple):
     """Device layout of the wavefront engine: node variables fused into
-    one array and ρ/ρ̃ stacked, so a wavefront commits with four scatters.
+    one array and ρ/ρ̃ stacked, so a wavefront writes its rows into four
+    arrays, in place (:func:`_write_rows`).
 
     Every array is *lane-dense*: the flat parameter axis is stored as
     ``(R, LANE)`` rows (``p_pad = R·LANE``, zero tail), the TPU's native
@@ -526,11 +528,11 @@ def _wave_step(
     p_real: int | None = None,
 ) -> tuple[PackedState, None]:
     """One wavefront: B independent per-agent updates (distinct agents,
-    pre-wavefront reads only — see build_wavefront_plan), committed as
-    disjoint O(p) row scatters.  Padding lanes carry sentinel indices:
-    their gathers clamp and their commits drop.  All plan-derived tables
-    arrive pre-gathered per lane, so the body reads only the four state
-    arrays.
+    pre-wavefront reads only — see build_wavefront_plan), whose O(p)
+    rows are written in place (:func:`_write_rows`).  Padding lanes carry
+    sentinel indices: their gathers clamp and their writes are skipped.
+    All plan-derived tables arrive pre-gathered per lane, so the body
+    reads only the four state arrays.
 
     ``impl="pallas"`` routes the S.2b/c + S.4 commit math (the
     bandwidth-bound tail) through ONE fused :func:`commit_grid` launch
@@ -562,11 +564,6 @@ def _wave_step(
 
         # (S.2a) consensus pull, reads resolved to delta-history rows ------
         vals_v = state.v_hist[w.rslot_v, w.src_v]          # (B, kw, R, L)
-        # order the v_hist write below after this read: nothing else
-        # does, and XLA would otherwise keep a whole copy of the ring to
-        # serve it
-        vals_v, v_hist = jax.lax.optimization_barrier((vals_v,
-                                                       state.v_hist))
         x_a = consensus_mix(bc(w.w_self), v_new, bc(w.w_in.swapaxes(0, 1)),
                             vals_v.swapaxes(0, 1))         # sum over kw
 
@@ -586,32 +583,79 @@ def _wave_step(
             jax.lax.optimization_barrier(g_new).reshape(x_a.shape))
 
     with jax.named_scope("rfast.commit"):
-        z_a, rho_new, rho_commit = _commit(state, w, z_l, gp_l, g_new,
-                                           ko=ko, impl=impl, mode=mode)
+        z_a, rho_new, buf_new = _commit(state, w, z_l, gp_l, g_new,
+                                        ko=ko, impl=impl, mode=mode)
 
-    # commit: disjoint row scatters; (S.4) ρ̃ rows take the consumed values
     with jax.named_scope("rfast.state_write"):
-        node_new = jnp.stack([x_a, v_new, z_a, g_new], axis=1)
-        return PackedState(
-            nodes=state.nodes.at[w.agent].set(node_new, mode="drop"),
-            rho2=state.rho2.at[w.rho_gidx].set(rho_commit, mode="drop"),
-            v_hist=v_hist.at[w.wslot, w.agent].set(v_new, mode="drop"),
-            rho_hist=state.rho_hist.at[w.wslot[:, None], w.rho_gidx[:, :ko]]
-            .set(rho_new, mode="drop"),
-        ), None
+        return _write_rows(state, w, (x_a, v_new, z_a, g_new), rho_new,
+                           buf_new, ko=ko), None
+
+
+def _write_rows(state: PackedState, w: _WaveInputs, node_new, rho_new,
+                buf_new, *, ko: int) -> PackedState:
+    """Write one wave's rows into the state in place.
+
+    Lane b with agent a writes ``nodes[a]`` (x, v, z, g_prev: the four
+    ``(B, R, LANE)`` arrays of ``node_new``) and ``v_hist[wslot, a]``;
+    its out-edge e writes ``rho_new`` into ``rho2[e]`` and
+    ``rho_hist[wslot, e]``, and its ρ̃ row r writes ``buf_new`` into
+    ``rho2[r]``.  Each row is one ``dynamic_update_slice`` under a
+    ``lax.cond`` on its index: a sentinel (padding lane or slot) takes
+    the branch that returns the buffer as it is, so no old row is read
+    to be written back.  Lanes are unrolled up to ``_UNROLL_LANES`` and
+    looped above that, which keeps wide fleet waves quick to compile.
+    """
+    # all writes after every read of the old rows (the commit's gathers
+    # and kernel): else XLA copies an array to serve a read it places
+    # after a write
+    node_new, rho_new, buf_new, state = jax.lax.optimization_barrier(
+        (node_new, rho_new, buf_new, state))
+    N, E = state.nodes.shape[0], state.rho_hist.shape[1]
+    zero = (0,) * (state.nodes.ndim - 2)
+
+    def put(ok, buf, rows, i, start):
+        def write(buf, rows):
+            row = rows[i]
+            row = row.reshape((1,) * (buf.ndim - row.ndim) + row.shape)
+            return jax.lax.dynamic_update_slice(buf, row, start)
+        return jax.lax.cond(ok, write, lambda buf, rows: buf, buf, rows)
+
+    def lane(b, st):
+        nodes, rho2, v_hist, rho_hist = st
+        a, slot = w.agent[b], w.wslot[b]
+        ok = (a >= 0) & (a < N)
+        for j, rows in enumerate(node_new):
+            nodes = put(ok, nodes, rows, b, (a, j) + zero)
+        v_hist = put(ok, v_hist, node_new[1], b, (slot, a) + zero)
+        for j in range(ko):
+            e = w.rho_gidx[b, j]
+            ok = (e >= 0) & (e < E)
+            rho2 = put(ok, rho2, rho_new, (b, j), (e,) + zero)
+            rho_hist = put(ok, rho_hist, rho_new, (b, j), (slot, e) + zero)
+        for j in range(buf_new.shape[1]):
+            r = w.rho_gidx[b, ko + j]
+            rho2 = put((r >= 0) & (r < 2 * E), rho2, buf_new, (b, j),
+                       (r,) + zero)
+        return nodes, rho2, v_hist, rho_hist
+
+    B = w.agent.shape[0]
+    return PackedState(*jax.lax.fori_loop(0, B, lane, tuple(state),
+                                          unroll=B <= _UNROLL_LANES))
 
 
 def _commit(state: PackedState, w: _WaveInputs, z_l, gp_l, g_new, *,
             ko: int, impl: str, mode: str):
-    """S.2b/c + S.4 of one wave: ``(z_a, rho_new, rho_commit)``, the new
-    z rows, the out-edge ρ rows and every ρ/ρ̃ row the wave writes."""
+    """S.2b/c + S.4 of one wave: ``(z_a, rho_new, buf_new)``, the new
+    z rows, the out-edge ρ rows ``(B, ko, R, L)`` and the consumed ρ̃
+    rows ``(B, ka, R, L)``."""
     bc = lambda a: a[..., None, None]
     if impl == "pallas" and mode != "interpret":
         # one fused launch for the whole wave: gather tables over the
         # state rows.  The kernel's masked ρ̃ blend equals the jnp
         # path's unconditional vals_rho commit: a_val is a 0/1 indicator
-        # and zero-mask rows scatter to the drop sentinel anyway.
-        # Sentinel lanes clamp inside commit_grid; their commits drop.
+        # and zero-mask rows carry the sentinel row index anyway.
+        # Sentinel lanes clamp inside commit_grid; their writes are
+        # skipped.
         rows = lambda a: a.reshape((-1,) + a.shape[-2:])
         nodes_flat = rows(state.nodes)                     # (N·4, R, L)
         idx_z, idx_g, idx_ri, idx_rb, idx_ro = grid_gather_tables(
@@ -622,7 +666,6 @@ def _commit(state: PackedState, w: _WaveInputs, z_l, gp_l, g_new, *,
             w.a_self, w.a_val, w.out_wt,
             nodes_flat, g_new, nodes_flat, rows(state.rho_hist),
             state.rho2, state.rho2, mode=mode)
-        rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
     elif impl == "pallas":
         # interpret-mode oracle: the original vmapped per-node kernel
         # over flat lanes.
@@ -640,7 +683,6 @@ def _commit(state: PackedState, w: _WaveInputs, z_l, gp_l, g_new, *,
             w.out_wt, w.a_self)
         lanes = lambda a: a.reshape(a.shape[:-1] + z_l.shape[-2:])
         z_a, rho_new, buf_new = lanes(z_a), lanes(rho_new), lanes(buf_new)
-        rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
     else:
         vals_rho = state.rho_hist[w.rslot_rho, w.hist_epos]  # (B, ka, R, L)
         rho_rows = state.rho2[w.rho_gidx]                    # (B, ko+ka, ..)
@@ -651,8 +693,8 @@ def _commit(state: PackedState, w: _WaveInputs, z_l, gp_l, g_new, *,
         z_a = bc(w.a_self) * z_half
         rho_new = rho_rows[:, :ko] \
             + bc(w.out_wt) * z_half[:, None]              # (B, ko, R, L)
-        rho_commit = jnp.concatenate([rho_new, vals_rho], axis=1)
-    return z_a, rho_new, rho_commit
+        buf_new = vals_rho
+    return z_a, rho_new, buf_new
 
 
 def rfast_wavefront_scan(
@@ -676,7 +718,7 @@ def rfast_wavefront_scan(
     is the tri-state dispatch override (None = autodetect: compiled on
     TPU, jnp emulation elsewhere; True = the vmapped per-node kernel in
     the Pallas interpreter, the tests-only oracle).  ``impl="jnp"`` is
-    the scatter/gather path.  ``p_real`` marks a block-padded flat axis
+    the jnp gather path.  ``p_real`` marks a block-padded flat axis
     (see :func:`_wave_step`).
     """
     if impl not in ("jnp", "pallas"):
@@ -1060,6 +1102,9 @@ def run_rfast(
             packed = runner(packed, chunk_waves)
         e = min(K, (ci + 1) * eval_every)
         tracing.count("rfast.events", e - ci * eval_every, ci)
+        tracing.count("rfast.pad_lanes",
+                      int(np.count_nonzero(wf.agent[w0:w1] == wf.n))
+                      + pad * wf.width, ci)
         if eval_fn is not None:
             with tracing.span("rfast.read_iterates", ci):
                 x = _iterates(packed, 0, n=plan.n, p=p)
@@ -1250,6 +1295,7 @@ def run_sweep(
             fleet = flatten_plans(stacked)
             if verify_plans:
                 diags += planlint.lint_flatten(stacked, fleet, subject="fleet")
+            pad_lanes = np.count_nonzero(fleet.agent == fleet.n, axis=1)
             waves = wave_inputs(fleet, step_keys.reshape(S_pad * K, 2))
             runner = rfast_sweep_scan(
                 grad_fn, gamma, ko=ko, n_per_lane=n, donate=True, impl=impl,
@@ -1258,13 +1304,15 @@ def run_sweep(
             # one flattened program PER lane group, stacked on the leading
             # device axis: every group shares the (cmax, S_loc·B) wave shape,
             # so the shard_map body compiles once for all groups
-            group_waves = []
+            group_waves, pad_lanes = [], 0
             for g in range(D):
                 stacked = stack_plans(rechunked[g * S_loc:(g + 1) * S_loc])
                 fleet = flatten_plans(stacked)
                 if verify_plans:
                     diags += planlint.lint_flatten(stacked, fleet,
                                                    subject=f"fleet/g{g}")
+                pad_lanes = pad_lanes + np.count_nonzero(
+                    fleet.agent == fleet.n, axis=1)
                 group_waves.append(wave_inputs(
                     fleet,
                     step_keys[g * S_loc:(g + 1) * S_loc].reshape(S_loc * K,
@@ -1302,6 +1350,8 @@ def run_sweep(
             packed = runner(packed, chunk_waves)
         e = min(K, (ci + 1) * eval_every)
         tracing.count("rfast.events", S * (e - chunk_starts[ci]), ci)
+        tracing.count("rfast.pad_lanes",
+                      int(pad_lanes[ci * cmax:(ci + 1) * cmax].sum()), ci)
         if eval_fn is not None:
             for s in range(S):
                 with tracing.span("rfast.read_iterates", ci):
@@ -1441,6 +1491,8 @@ def _scan_epochs(epochs, plans, wfs, bounds, runner, step_keys, state0,
             packed = runner(packed, w)
             e_loc = min(ep.K, (ci + 1) * eval_every)
             tracing.count("rfast.events", e_loc - ci * eval_every)
+            tracing.count("rfast.pad_lanes", int(np.count_nonzero(
+                rc.agent[ci * cmax:(ci + 1) * cmax] == rc.n)))
             kg = ep.k0 + e_loc
             if eval_fn is not None:
                 m = eval_fn(_iterates(packed, 0, n=packed.nodes.shape[0],

@@ -38,9 +38,9 @@ Commit math per lane b (identical to :func:`.ref.rfast_commit_ref`):
 
 Index tables must be pre-clamped into their source's row range by the
 caller (:func:`repro.core.schedule.grid_gather_tables`): drop-sentinel
-lanes clamp to a valid row, read garbage weighted by zero, and their
-commits are discarded by the caller's drop-mode scatters — exactly the
-inertness contract of the jnp wavefront path.
+lanes clamp to a valid row, read garbage weighted by zero, and the
+caller skips their writes — exactly the inertness contract of the jnp
+wavefront path.
 """
 from __future__ import annotations
 
@@ -215,8 +215,8 @@ def commit_grid(idx_z, idx_g, idx_ri, idx_rb, idx_ro,
 
     Returns ``(z' (B, *row), rho_out' (B, ko, *row), rho_buf' (B, ka,
     *row))`` in the respective source dtypes.  All index tables are clamped into
-    their source's row range (drop-sentinel lanes must be discarded by
-    the caller's scatters).
+    their source's row range (the caller must skip the writes of
+    drop-sentinel lanes).
     """
     if mode is None:
         mode = dispatch.resolve_mode(None)

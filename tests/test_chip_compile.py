@@ -104,3 +104,61 @@ def test_commit_grid_compiles_per_shard_on_2x2_mesh(topo):
     assert "tpu_custom_call" in compiled.as_text()
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 16 * 2**30
+
+
+def _wave_structs(sharding, *, N, E, H, B, kw, ka, ko, width, waves=20):
+    """Shapes of one chunk of ``run_waves``: the packed state and the
+    wave tables."""
+    from repro.core.simulator import PackedState, _WaveInputs
+    row = (width // LANE, LANE)
+    s = lambda dt, *sh: jax.ShapeDtypeStruct(sh, dt, sharding=sharding)
+    f = lambda *sh: s(jnp.float32, *sh)
+    i = lambda *sh: s(jnp.int32, waves, B, *sh)
+    fw = lambda *sh: s(jnp.float32, waves, B, *sh)
+    state = PackedState(nodes=f(N, 4, *row), rho2=f(2 * E, *row),
+                        v_hist=f(H, N, *row), rho_hist=f(H, E, *row))
+    return state, _WaveInputs(
+        agent=i(), wslot=i(), w_self=fw(), a_self=fw(), rslot_v=i(kw),
+        src_v=i(kw), w_in=fw(kw), rslot_rho=i(ka), hist_epos=i(ka),
+        a_val=fw(ka), rho_gidx=i(ko + ka), out_wt=fw(ko),
+        keys=s(jnp.uint32, waves, B, 2))
+
+
+@pytest.mark.parametrize("case", ["full_width_one_chip", "wide_wave_b128"])
+def test_state_writes_compile_in_place_for_v5e(topo, case):
+    """The wave's row writes compile to in-place updates: no copy of a
+    whole state array, no loop but the chunk's scan (and, on a wide
+    wave, its lane loop), no scatter, and no write that selects between
+    the new row and the old one it read back."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import binary_tree
+    from repro.core.plan import build_comm_plan
+    from repro.core.simulator import (_UNROLL_LANES, rfast_sweep_scan,
+                                      rfast_wavefront_scan)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    grad = lambda i, x, key: 0.5 * x
+    if case == "full_width_one_chip":
+        # the benchmark cell: n=2 binary tree, H=3, one lane per wave
+        plan = build_comm_plan(binary_tree(2))
+        run = rfast_wavefront_scan(plan, grad, 0.1, impl="pallas",
+                                   interpret=False, p_real=P_100M)
+        shape = dict(N=2, E=max(1, plan.n_edges_a), H=3, B=1, kw=plan.kw,
+                     ka=plan.ka, ko=plan.ko, width=block_pad_width(P_100M))
+    else:
+        run = rfast_sweep_scan(grad, 0.1, ko=2, n_per_lane=4,
+                               impl="pallas", interpret=False)
+        shape = dict(N=4 * 64, E=63, H=4, B=128, kw=2, ka=2, ko=2,
+                     width=block_pad_width(P_100M, 512) // 512)
+    state, waves = _wave_structs(one_chip, **shape)
+    text = run.lower(state, waves).compile().as_text()
+
+    whole = {tuple(a.shape) for a in state}
+    copies = [tuple(int(d) for d in m.split(",")) for m in re.findall(
+        r"= f32\[([0-9,]+)\]\{[^}]*\} copy\(", text)]
+    assert not [c for c in copies if c in whole], copies
+    loops = 1 + (shape["B"] > _UNROLL_LANES)
+    assert len(re.findall(r" while\(", text)) == loops
+    assert " scatter(" not in text
+    assert not re.findall(r"= f32\[[^\]]*\]\{[^}]*\} select\([^)]*"
+                          r"dynamic-slice", text)

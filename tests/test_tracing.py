@@ -108,7 +108,18 @@ def test_engine_records_plan_init_chunks_and_events(tracing, engine):
         assert [s["id"] for s in by[name]] == list(range(chunks)), name
         assert all(s["parent"] is None for s in by[name])
     assert metrics.total("rfast.events") == K
-    assert [c["id"] for c in rec["counts"]] == list(range(chunks))
+    counts = {}
+    for c in rec["counts"]:
+        counts.setdefault(c["name"], []).append(c)
+    assert set(counts) == {"rfast.events", "rfast.pad_lanes"}
+    for name, cs in counts.items():
+        assert [c["id"] for c in cs] == list(range(chunks)), name
+    # every chunk runs the same number of wave lanes: each one either
+    # commits an event or is a padding lane whose writes are skipped
+    lanes = {e["n"] + q["n"] for e, q in zip(counts["rfast.events"],
+                                             counts["rfast.pad_lanes"])}
+    assert len(lanes) == 1
+    assert metrics.total("rfast.pad_lanes") > 0
 
 
 def _compiled_text(program):
@@ -172,6 +183,6 @@ def test_train_metrics_writes_events_per_s_and_the_record(tmp_path):
     spans = [r["name"] for r in rec if r["kind"] == "span"]
     assert spans.count("rfast.plan") == spans.count("rfast.init_state") == 1
     assert spans.count("rfast.run_waves") == 2
-    assert sum(r["n"] for r in rec if r["kind"] == "count") \
-        == out["events"] == 8
+    assert sum(r["n"] for r in rec if r["kind"] == "count"
+               and r["name"] == "rfast.events") == out["events"] == 8
     assert not metrics.enabled() and metrics.record()["spans"] == []

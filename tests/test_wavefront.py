@@ -246,3 +246,91 @@ def test_donated_round_updates_in_place_semantics():
         finals[donate] = np.asarray(state.x["w"])
     np.testing.assert_allclose(finals[False], finals[True],
                                rtol=1e-6, atol=1e-6)
+
+
+def _drop_writes(state, w, node_new, rho_new, buf_new, *, ko):
+    """The drop-mode scatter formulation of one wave's state write: each
+    sentinel index drops its row."""
+    from repro.core.simulator import PackedState
+    rho_commit = jnp.concatenate([rho_new, buf_new], axis=1)
+    return PackedState(
+        nodes=state.nodes.at[w.agent].set(jnp.stack(node_new, axis=1),
+                                          mode="drop"),
+        rho2=state.rho2.at[w.rho_gidx].set(rho_commit, mode="drop"),
+        v_hist=state.v_hist.at[w.wslot, w.agent].set(node_new[1],
+                                                     mode="drop"),
+        rho_hist=state.rho_hist.at[w.wslot[:, None], w.rho_gidx[:, :ko]]
+        .set(rho_new, mode="drop"))
+
+
+def _random_waves(rng, *, n_waves, B, N, E, H, ko, ka, kw=2):
+    """Wave tables as a plan builds them: distinct real agents and ρ rows
+    within a wave, sentinel agent N and sentinel rows 2E on padding
+    lanes (at random positions), and sentinel rows on some real lanes
+    too (nodes of a lower degree)."""
+    from repro.core.simulator import _WaveInputs
+    agent = np.full((n_waves, B), N)
+    gidx = np.full((n_waves, B, ko + ka), 2 * E)
+    for t in range(n_waves):
+        real = rng.random(B) < 0.6
+        agent[t, real] = rng.choice(N, real.sum(), replace=False)
+        for cols, lo in ((range(ko), 0), (range(ko, ko + ka), E)):
+            free = list(rng.permutation(E) + lo)
+            for b in np.flatnonzero(real):
+                for c in cols:
+                    if free and rng.random() < 0.7:
+                        gidx[t, b, c] = free.pop()
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    f32 = lambda *s: jnp.asarray(rng.normal(0, 1, s), jnp.float32)
+    return _WaveInputs(
+        agent=i32(agent), wslot=i32(rng.integers(0, H, (n_waves, B))),
+        w_self=f32(n_waves, B), a_self=f32(n_waves, B),
+        rslot_v=i32(rng.integers(0, H, (n_waves, B, kw))),
+        src_v=i32(rng.integers(0, N, (n_waves, B, kw))),
+        w_in=f32(n_waves, B, kw),
+        rslot_rho=i32(rng.integers(0, H, (n_waves, B, ka))),
+        hist_epos=i32(rng.integers(0, E, (n_waves, B, ka))),
+        a_val=jnp.asarray(rng.random((n_waves, B, ka)) < 0.5, jnp.float32),
+        rho_gidx=i32(gidx), out_wt=f32(n_waves, B, ko),
+        keys=jnp.asarray(rng.integers(0, 2**31, (n_waves, B, 2)),
+                         jnp.uint32))
+
+
+@pytest.mark.parametrize("engine", ["wavefront", "sweep"])
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("E", [1, 3])
+@pytest.mark.parametrize("ko", [1, 2])
+@pytest.mark.parametrize("B", [1, 3, 8, 12])
+def test_state_write_matches_drop_scatters(monkeypatch, B, ko, E, impl,
+                                           engine):
+    """The in-place row writes leave the packed state bit-identical to
+    drop-mode scatters, over several waves of random state and tables
+    (B = 12 takes the lane loop, the others the unrolled lanes)."""
+    from repro.core import simulator
+    from repro.core.plan import pad_comm_plan
+    N, H, ka, R = 10, 3, 2, 2
+    rng = np.random.default_rng([B, ko, E])
+    row = (R, 128)
+    st = simulator.PackedState(*(
+        jnp.asarray(rng.normal(0, 1, s + row), jnp.float32)
+        for s in [(N, 4), (2 * E,), (H, N), (H, E)]))
+    waves = _random_waves(rng, n_waves=4, B=B, N=N, E=E, H=H, ko=ko,
+                          ka=ka)
+    grad = lambda i, x, key: 0.5 * x - 0.01 * i
+
+    def run():
+        if engine == "sweep":
+            runner = simulator.rfast_sweep_scan(
+                grad, 0.05, ko=ko, n_per_lane=N, donate=False, impl=impl)
+        else:
+            plan = pad_comm_plan(build_comm_plan(binary_tree(N)), ko=ko)
+            runner = simulator.rfast_wavefront_scan(
+                plan, grad, 0.05, donate=False, impl=impl)
+        return runner(st, waves)
+
+    new = run()
+    monkeypatch.setattr(simulator, "_write_rows", _drop_writes)
+    old = run()
+    for f, a, b in zip(simulator.PackedState._fields, new, old):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f)
